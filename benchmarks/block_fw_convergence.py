@@ -117,6 +117,7 @@ def _cell(task, ndev, *, d, m, rank, n, obs, budget, frac, k, timeout):
     )
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
     label = "serial" if ndev == 1 else f"sharded{ndev}"
     name = f"blockfw.{task}.{label}"
     proc = subprocess.run([sys.executable, "-c", script], capture_output=True,

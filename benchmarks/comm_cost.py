@@ -68,7 +68,7 @@ from repro import comm as comm_lib
 
 P = json.loads('PARAMS')
 n, d, m, K, nw = P["n"], P["d"], P["m"], P["K"], P["workers"]
-mesh = jax.make_mesh((nw,), ("data",))
+mesh = jax.make_mesh((nw,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 if P.get("task", "mtls") == "mc":
     # COO completion state: p observed-entry slots per epoch; the epoch's
     # collectives ((d,)/(m,) vector reduces + 4 scalars) match MTLS's.
@@ -165,6 +165,7 @@ def _run_subprocess(template: str, params: dict) -> dict:
     script = template.replace("SRC", src).replace("PARAMS", json.dumps(params))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=1200, env=env)
     if out.returncode != 0:

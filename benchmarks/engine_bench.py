@@ -143,6 +143,7 @@ def run(d=256, m=256, n=64, epochs=192, block=32):
               .replace("__BLOCK__", str(block)))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
     proc = subprocess.run([sys.executable, "-c", script], capture_output=True,
                           text=True, timeout=900, env=env)
     if proc.returncode != 0:

@@ -112,6 +112,7 @@ def _run_subprocess(template: str, params: dict) -> dict:
     script = template.replace("SRC", src).replace("PARAMS", json.dumps(params))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=1200, env=env)
     if out.returncode != 0:

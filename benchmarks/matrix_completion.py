@@ -86,6 +86,7 @@ def _worker_scaling(d, m, obs, epochs):
         )
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
         out = subprocess.run([sys.executable, "-c", script],
                              capture_output=True, text=True, timeout=900, env=env)
         if out.returncode != 0:

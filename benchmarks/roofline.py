@@ -20,8 +20,11 @@ import json
 from pathlib import Path
 from typing import Dict, List, Optional
 
-PEAK = 197e12
-HBM = 819e9
+from repro.launch.mesh import device_peaks
+
+# The dry-run cells model a v5e pod; per-link ICI bandwidth is this
+# analysis's own assumption (not in the published per-chip table).
+PEAK, HBM = device_peaks("TPU v5 lite")
 ICI = 50e9
 
 DRYRUN_DIR = Path(__file__).resolve().parent.parent / "experiments" / "dryrun"

@@ -5,6 +5,7 @@
 from __future__ import annotations
 
 import argparse
+import importlib
 import sys
 import traceback
 
@@ -19,58 +20,46 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    from . import (
-        block_fw_convergence,
-        comm_cost,
-        dfw_scaling,
-        engine_bench,
-        gossip_consensus,
-        imagenet_head,
-        kernel_bench,
-        logistic_convergence,
-        matrix_completion,
-        mtls_convergence,
-        power_accuracy,
-        roofline,
-        scaling,
-        serving_latency,
-    )
+    # Suites import lazily, so `--only X` imports (and initializes JAX for)
+    # X alone: the parent touches no device before a suite that starts
+    # fake-device children.
+    def suite(module: str, fn: str = "run", **kw):
+        return lambda: getattr(
+            importlib.import_module(f"{__package__}.{module}"), fn
+        )(**kw)
 
+    fast = args.fast
     suites = {
-        "table1_comm_cost": comm_cost.run,
-        "table1_comm_sweep": (lambda: comm_cost.run_sweep(fast=True))
-        if args.fast else comm_cost.run_sweep,
+        "table1_comm_cost": suite("comm_cost"),
+        "table1_comm_sweep": suite("comm_cost", "run_sweep", fast=fast),
         # gossip_consensus keeps the gated hier.inter_bytes record at the
         # same sizes in --fast: it is an HLO byte ratio of one compiled
         # exchange, immune to runner speed; only the fit epochs shrink.
-        "gossip_consensus": (lambda: gossip_consensus.run(fast=True))
-        if args.fast else gossip_consensus.run,
-        "fig1_mtls": (lambda: mtls_convergence.run(epochs=15, n=8000, d=128, m=128))
-        if args.fast else mtls_convergence.run,
-        "fig2_logistic": (lambda: logistic_convergence.run(epochs=12, n=4000, d=96, m=48))
-        if args.fast else logistic_convergence.run,
-        "fig3_imagenet_head": (lambda: imagenet_head.run(epochs=15, m=50, tokens=2048))
-        if args.fast else imagenet_head.run,
-        "fig4_scaling": scaling.run,
-        "fig4_dfw_scaling": (lambda: dfw_scaling.run(n=2048, d=64, m=32, epochs=5))
-        if args.fast else dfw_scaling.run,
-        "fig5_matrix_completion": (
-            lambda: matrix_completion.run(d=128, m=96, obs=0.3, epochs=8))
-        if args.fast else matrix_completion.run,
-        "engine_overhead": (lambda: engine_bench.run(epochs=96, block=24))
-        if args.fast else engine_bench.run,
+        "gossip_consensus": suite("gossip_consensus", fast=fast),
+        "fig1_mtls": suite("mtls_convergence",
+                           **(dict(epochs=15, n=8000, d=128, m=128) if fast else {})),
+        "fig2_logistic": suite("logistic_convergence",
+                               **(dict(epochs=12, n=4000, d=96, m=48) if fast else {})),
+        "fig3_imagenet_head": suite("imagenet_head",
+                                    **(dict(epochs=15, m=50, tokens=2048) if fast else {})),
+        "fig4_scaling": suite("scaling"),
+        "fig4_dfw_scaling": suite("dfw_scaling",
+                                  **(dict(n=2048, d=64, m=32, epochs=5) if fast else {})),
+        "fig5_matrix_completion": suite(
+            "matrix_completion", **(dict(d=128, m=96, obs=0.3, epochs=8) if fast else {})),
+        "engine_overhead": suite("engine_bench",
+                                 **(dict(epochs=96, block=24) if fast else {})),
         # serving_latency keeps Table-1 sizes even in --fast: the gated
         # record IS the rank=d/8 point at d=m=1024; only repetitions shrink.
-        "serving_latency": (
-            lambda: serving_latency.run(ranks=(16, 128), dispatches=15))
-        if args.fast else serving_latency.run,
+        "serving_latency": suite("serving_latency",
+                                 **(dict(ranks=(16, 128), dispatches=15) if fast else {})),
         # block_fw_convergence keeps Table-1 sizes even in --fast: the
         # gated epochs_to_gap.speedup records ARE the d=m=1024 cells (the
         # metric is an epoch-count ratio, immune to runner speed).
-        "block_fw_convergence": block_fw_convergence.run,
-        "thm2_power_accuracy": power_accuracy.run,
-        "kernels": kernel_bench.run,
-        "roofline": roofline.run,
+        "block_fw_convergence": suite("block_fw_convergence"),
+        "thm2_power_accuracy": suite("power_accuracy"),
+        "kernels": suite("kernel_bench"),
+        "roofline": suite("roofline"),
     }
     if args.only:
         suites = {args.only: suites[args.only]}

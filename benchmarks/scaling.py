@@ -35,7 +35,7 @@ if NDEVN == 1:
     step = frank_wolfe.make_epoch_step(task, 1.0, K, step_size="linesearch")
     wrapped = step
 else:
-    mesh = jax.make_mesh((NDEVN,), ("data",))
+    mesh = jax.make_mesh((NDEVN,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     ss = tasks.MTLSState(x=P("data"), y=P("data"), r=P("data"))
     isp = low_rank.FactoredIterate(u=P(), s=P(), v=P(), alpha=P(), count=P())
     asp = frank_wolfe.EpochAux(P(), P(), P(), P(), P())
@@ -72,6 +72,7 @@ def run():
             script = _SCRIPT.replace("__NDEV__", str(ndev)).replace("SRC", src)
             env = dict(os.environ)
             env.pop("XLA_FLAGS", None)
+            env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
             out = subprocess.run([sys.executable, "-c", script],
                                  capture_output=True, text=True, timeout=600, env=env)
             if out.returncode != 0:

@@ -40,7 +40,7 @@ w_star = jax.random.normal(key, (x.shape[1], 10)) @ jax.random.normal(
 y = jnp.argmax(x @ w_star, axis=1)
 
 # --- 3. distributed DFW-TRACE over 8 workers -------------------------------
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 res = dfw_head.sharded_fit(mesh, x, y, m, mu=20.0, num_epochs=40,
                            schedule="const:2")
 err5 = dfw_head.top_k_error(res.iterate, x, y, k=5)

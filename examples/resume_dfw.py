@@ -58,6 +58,7 @@ print("RESULT " + json.dumps({
 def run_worker(nw, mode, kill_after=None):
     env = {**os.environ, "PYTHONPATH": SRC}
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device child: never contend for a TPU
     proc = subprocess.Popen(
         [sys.executable, "-c", WORKER, CKPT, str(nw), mode],
         stdout=subprocess.PIPE, text=True, env=env,

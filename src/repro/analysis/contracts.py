@@ -252,7 +252,7 @@ def verify_declared(verbose: bool = True) -> int:
     K, n, m = 3, 512, 48
     c = power_method.collective_rounds_contract(K)
     try:
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def run(a, v0):
             return power_method.power_iterations(
@@ -278,7 +278,7 @@ def verify_declared(verbose: bool = True) -> int:
     Kb, kb = 3, 4
     c = power_method.block_collective_rounds_contract(Kb, kb)
     try:
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def run_block(a, v0):
             return power_method.block_power_iterations(

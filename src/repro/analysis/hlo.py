@@ -316,6 +316,12 @@ class HloModule:
         return walk(entry)
 
 
+def tpu_kernel_calls(hlo_text: str) -> int:
+    """Static call sites of Pallas/Mosaic kernels in a compiled module: 0
+    means every kernel-routed op fell back to plain XLA."""
+    return hlo_text.count('custom_call_target="tpu_custom_call"')
+
+
 def analyze(hlo_text: str, top_k: int = 12) -> Dict:
     mod = HloModule(hlo_text)
     c = mod.total_costs()

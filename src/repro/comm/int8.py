@@ -99,8 +99,14 @@ def verify_quantize_kernels(
 ) -> float:
     """Startup check (same role as ``launch/dfw.verify_kernelized``): the
     dispatched quantize/dequantize pair must match the jnp reference on a
-    random probe — both paths consume the same explicit noise, so agreement
-    is exact up to f32 rounding. Returns the max abs error observed."""
+    random probe. Returns the max abs error observed.
+
+    Agreement is exact, on TPU as on CPU: both paths consume the same
+    explicit noise and the same XLA-computed factors budget/scale and
+    scale/budget (``kernels/quantize/ref.py``), leaving the kernel only
+    exactly-rounded f32 multiplies, adds and floors. There is no matmul, so
+    no precision mode applies. One integer off is an error of 1, far past
+    ``tol``."""
     from ..kernels.quantize import ref as qref
 
     b = max(1, 127 // num_workers)

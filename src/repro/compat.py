@@ -1,29 +1,15 @@
-"""Version bridges for the moving jax API surface.
+"""One import for ``jax.shard_map`` with replication checking off.
 
-``shard_map`` left ``jax.experimental`` and its replication-check flag was
-renamed ``check_rep`` -> ``check_vma`` along the way; this module gives the
-rest of the codebase one import that works on both sides. Keep this a leaf
-module (jax-only imports) so ``core/``, ``models/`` and ``launch/`` can all
-depend on it without cycles.
+Keep this a leaf module (jax-only imports) so ``core/``, ``models/`` and
+``launch/`` can all depend on it without cycles.
 """
 from __future__ import annotations
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, on any supported jax.
-
-    ``mesh`` is forwarded by keyword: it is keyword-only in the top-level
-    jax >= 0.5 API and positional-or-keyword in jax.experimental's.
-    """
+    """``jax.shard_map`` with ``check_vma=False`` (``mesh`` is keyword-only)."""
     return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KW: False}
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

@@ -548,7 +548,8 @@ def run_epochs(
         try:
             from ..analysis import hlo as hlo_lib
 
-            info = hlo_lib.analyze(exe.as_text())
+            text = exe.as_text()
+            info = hlo_lib.analyze(text)
         except Exception:  # pragma: no cover - HLO text formats drift
             return
         tel.event(
@@ -556,6 +557,7 @@ def run_epochs(
             hlo_collective_bytes=info["collective_bytes_total"],
             hlo_collective_count={k: v for k, v in info["collective_count"].items()},
             hlo_flops=info["flops"],
+            tpu_kernel_calls=hlo_lib.tpu_kernel_calls(text),
         )
 
     # Analytic per-segment comm cost: 2*K *exchanges* per epoch (K for

@@ -18,8 +18,9 @@ blocks) with the out axis innermost:
 so X and A are read exactly once per batch block and B exactly once per
 call — the information-theoretic minimum for the two-stage product. Both
 dots accumulate in f32 via ``preferred_element_type`` regardless of input
-dtype. The running iterate scale ``alpha`` is folded into ``s`` by the ops
-layer, so the kernel itself is scale-free.
+dtype, and f32 operands multiply at f32 precision. The running iterate
+scale ``alpha`` is folded into ``s`` by the ops layer, so the kernel itself
+is scale-free.
 
 Rows of A/B at indices >= the live rank carry s == 0 (``low_rank`` zeroes
 them by construction), so rank padding — like batch padding — is an exact
@@ -34,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..precision import mxu_precision
+
 
 def _factor_matvec_kernel(x_ref, a_ref, s_ref, b_ref, o_ref, t_ref):
     """o[i, j] = ((x[i] @ a^T) * s) @ b[j]; grid=(batch, out), out innermost."""
@@ -41,15 +44,16 @@ def _factor_matvec_kernel(x_ref, a_ref, s_ref, b_ref, o_ref, t_ref):
 
     @pl.when(j == 0)
     def _stage1():
+        x, a = x_ref[...], a_ref[...]
         t_ref[...] = (
-            jnp.dot(
-                x_ref[...], a_ref[...].T, preferred_element_type=jnp.float32
-            )
+            jnp.dot(x, a.T, precision=mxu_precision(x, a),
+                    preferred_element_type=jnp.float32)
             * s_ref[...].T
         )
 
+    t, b = t_ref[...], b_ref[...]
     o_ref[...] = jnp.dot(
-        t_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        t, b, precision=mxu_precision(t, b), preferred_element_type=jnp.float32
     )
 
 

@@ -14,7 +14,8 @@ once per call (one HBM pass over the shard) while the dense vectors stay
 resident. The extra one-hot FLOPs are the standard TPU trade for
 bandwidth-bound sparse ops — each is ``block_e * dim`` MACs on the MXU, and
 the entry shard, not the dense work, is the traffic that matters.
-Accumulation is always f32 via ``preferred_element_type``.
+Accumulation is always f32 via ``preferred_element_type``, and the one-hot
+products run at f32 precision so gathered values are not rounded to bf16.
 """
 from __future__ import annotations
 
@@ -23,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..precision import mxu_precision
 
 
 def _coo_matvec_kernel(seg_ref, gat_ref, vals_ref, x_ref, o_ref):
@@ -43,15 +46,19 @@ def _coo_matvec_kernel(seg_ref, gat_ref, vals_ref, x_ref, o_ref):
     gather = (
         jax.lax.broadcasted_iota(jnp.int32, (block_e, in_dim), 1) == gat
     ).astype(jnp.float32)
+    hi = mxu_precision(gather)  # f32 one-hots: the values ride at f32
     xe = jnp.dot(
-        gather, x_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
+        gather, x_ref[...].astype(jnp.float32), precision=hi,
+        preferred_element_type=jnp.float32,
     )
     contrib = vals * xe  # (block_e, 1)
 
     scatter = (
         jax.lax.broadcasted_iota(jnp.int32, (block_e, out_dim), 1) == seg
     ).astype(jnp.float32)
-    o_ref[...] += jnp.dot(scatter.T, contrib, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(
+        scatter.T, contrib, precision=hi, preferred_element_type=jnp.float32
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("out_dim", "block_e", "interpret"))

@@ -7,7 +7,8 @@ pass over the matrix per call with MXU-aligned (block_r x block_c) VMEM tiles;
 vectors are carried as (len, 1) matrices so the reduction runs on the MXU.
 
 Accumulation is always f32 via ``preferred_element_type`` regardless of the
-input dtype (bf16 inputs keep full-precision partial sums).
+input dtype (bf16 inputs keep full-precision partial sums); f32 inputs are
+multiplied at f32 precision (``..precision``).
 """
 from __future__ import annotations
 
@@ -16,6 +17,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..precision import mxu_precision
 
 
 def _matvec_kernel(a_ref, v_ref, o_ref):
@@ -26,8 +29,9 @@ def _matvec_kernel(a_ref, v_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    a, v = a_ref[...], v_ref[...]
     o_ref[...] += jnp.dot(
-        a_ref[...], v_ref[...], preferred_element_type=jnp.float32
+        a, v, precision=mxu_precision(a, v), preferred_element_type=jnp.float32
     )
 
 
@@ -39,8 +43,9 @@ def _rmatvec_kernel(a_ref, u_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    a, u = a_ref[...], u_ref[...]
     o_ref[...] += jnp.dot(
-        a_ref[...].T, u_ref[...], preferred_element_type=jnp.float32
+        a.T, u, precision=mxu_precision(a, u), preferred_element_type=jnp.float32
     )
 
 

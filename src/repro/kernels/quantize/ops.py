@@ -51,7 +51,7 @@ def quantize(
     xp = _pad1(x.astype(jnp.float32), block_n).reshape(-1, 1)
     np_ = _pad1(noise.astype(jnp.float32), block_n).reshape(-1, 1)
     out = kernel.quantize(
-        xp, np_, scale.reshape(1, 1),
+        xp, np_, ref.inverse_step(scale, budget).reshape(1, 1),
         budget=budget, block_n=block_n, interpret=interpret,
     )
     return out[:n, 0]
@@ -76,7 +76,7 @@ def dequantize(
         return ref.dequantize(q, scale, budget)
     qp = _pad1(q, block_n).reshape(-1, 1)
     out = kernel.dequantize(
-        qp, scale.reshape(1, 1),
+        qp, ref.step(scale, budget).reshape(1, 1),
         budget=budget, block_n=block_n, interpret=interpret,
     )
     return out[:n, 0]

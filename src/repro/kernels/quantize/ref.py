@@ -1,8 +1,8 @@
 """Pure-jnp oracles for the quantize kernels.
 
-Bit-compatible with ``kernel.py`` (the stochastic noise is an explicit
-operand, so both paths compute the identical floor), used by tests and as
-the off-TPU dispatch target of ``ops.py``.
+Bit-compatible with ``kernel.py`` (the stochastic noise and the scalar
+factors are explicit operands, so both paths compute the identical floor),
+used by tests and as the off-TPU dispatch target of ``ops.py``.
 """
 from __future__ import annotations
 
@@ -22,10 +22,23 @@ def quantize(
     ``|x| <= scale`` the clip never binds (floor of a value in
     [-budget, budget + 1) lands in [-budget, budget]).
     """
-    v = jnp.floor(x.astype(jnp.float32) * (budget / (scale + _EPS)) + noise)
+    v = jnp.floor(x.astype(jnp.float32) * inverse_step(scale, budget) + noise)
     return jnp.clip(v, -budget, budget).astype(jnp.int8)
 
 
 def dequantize(q: jax.Array, scale: jax.Array, budget: int) -> jax.Array:
     """q * scale / budget as f32 (q is the *summed* integer vector)."""
-    return q.astype(jnp.float32) * (scale / budget)
+    return q.astype(jnp.float32) * step(scale, budget)
+
+
+# The two scalar factors are computed here, in XLA, for both paths: the
+# kernels receive them as operands instead of dividing themselves, so a
+# division that rounds differently inside a kernel cannot move a floor.
+def inverse_step(scale: jax.Array, budget: int) -> jax.Array:
+    """budget / scale: f32 value -> integer units."""
+    return budget / (scale + _EPS)
+
+
+def step(scale: jax.Array, budget: int) -> jax.Array:
+    """scale / budget: integer units -> f32 value."""
+    return scale / budget

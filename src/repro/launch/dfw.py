@@ -46,6 +46,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import comm as comm_lib, specs
@@ -60,6 +61,7 @@ from ..kernels.power_matvec import ops as pm_ops
 from . import mesh as mesh_lib
 
 PyTree = Any
+_F32 = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -200,11 +202,16 @@ class DFWFitResult:
 
 def data_mesh(num_workers: int, axis: str = "data") -> Mesh:
     """1-D mesh over the first ``num_workers`` devices (the paper's workers)."""
-    if num_workers > len(jax.devices()):
-        raise ValueError(
-            f"num_workers={num_workers} > visible devices={len(jax.devices())}; "
-            "on CPU set XLA_FLAGS=--xla_force_host_platform_device_count=N "
+    devices = jax.devices()
+    if num_workers > len(devices):
+        hint = (
+            "; on CPU set XLA_FLAGS=--xla_force_host_platform_device_count=N "
             "before the first jax import"
+            if devices[0].platform == "cpu" else ""
+        )
+        raise ValueError(
+            f"num_workers={num_workers} > visible {devices[0].platform} "
+            f"devices={len(devices)}{hint}"
         )
     return mesh_lib.make_mesh((num_workers,), (axis,))
 
@@ -219,11 +226,15 @@ def replicated_specs(tree: PyTree) -> PyTree:
 
 
 def shard_rowwise(mesh: Mesh, tree: PyTree, axis: str = "data") -> PyTree:
-    """Place every leaf row-sharded over ``axis``; leading dims must divide."""
+    """Place every leaf row-sharded over ``axis``; leading dims must divide.
+
+    Host (numpy) leaves go straight to their shards, each device receiving
+    only its own rows; they are never first assembled on one device."""
     nw = mesh.shape[axis]
 
     def place(x):
-        x = jnp.asarray(x)
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
         if x.shape[0] % nw:
             raise ValueError(
                 f"leading dim {x.shape[0]} not divisible by {nw} workers; "
@@ -232,6 +243,33 @@ def shard_rowwise(mesh: Mesh, tree: PyTree, axis: str = "data") -> PyTree:
         return jax.device_put(x, NamedSharding(mesh, P(axis)))
 
     return jax.tree.map(place, tree)
+
+
+def init_rowwise_state(task, xs: jax.Array, ys: jax.Array, axis: str = "data"):
+    """``task.init_state`` on row-sharded data, without copying the data.
+
+    Leaves the task creates (e.g. the logistic Z) are built under jit with
+    row-sharded outputs, so each is born on its workers' devices. Leaves
+    that are the data itself are handed back as they are: a jit output
+    would be a second copy of X."""
+    data, which, treedef = (xs, ys), {}, []
+
+    def created(x, y):
+        leaves, tdef = jax.tree.flatten(task.init_state(x, y))
+        treedef.append(tdef)
+        for i, leaf in enumerate(leaves):
+            for j, arg in enumerate((x, y)):
+                if leaf is arg:
+                    which[i] = j
+        return [leaf for i, leaf in enumerate(leaves) if i not in which]
+
+    made = iter(jax.jit(
+        created, out_shardings=NamedSharding(xs.sharding.mesh, P(axis))
+    )(xs, ys))
+    return jax.tree.unflatten(treedef[0], [
+        data[which[i]] if i in which else next(made)
+        for i in range(treedef[0].num_leaves)
+    ])
 
 
 def shard_observations(
@@ -259,8 +297,6 @@ def shard_observations(
     downstream gather/segment chains clip silently). Runs on host (numpy):
     this is one-time data layout, not epoch work.
     """
-    import numpy as np
-
     # Explicit boundary (no-op on numpy inputs): a caller handing device
     # arrays gets one batched fetch, not four implicit pulls (REP002).
     rows, cols, vals, weight = jax.device_get((rows, cols, vals, weight))
@@ -335,11 +371,14 @@ class KernelizedTask:
     # -- implicit-gradient operator, kernel-routed per state type ----------
     # (MTLSDenseState is not handled: the dense task lacks the epoch
     # interface — local_loss/inner_w_grad — so the drivers here can't run it.)
+    # The logistic P-products stay in XLA; they are pinned to f32 precision
+    # (TPU's default would round their operands to bf16) so the whole
+    # operator agrees with the f32 maths ``verify_kernelized`` checks.
     def matvec(self, s, v: jax.Array) -> jax.Array:
         if isinstance(s, tasks.MTLSState):  # A = X^T R
             return pm_ops.rmatvec(s.x, pm_ops.matvec(s.r, v, **self._kw), **self._kw)
         if isinstance(s, tasks.LogisticState):  # A = X^T (P - H)
-            pv = self._base._probs(s) @ v - v[s.y]
+            pv = jnp.dot(self._base._probs(s), v, precision=_F32) - v[s.y]
             return pm_ops.rmatvec(s.x, pv, **self._kw)
         if isinstance(s, tasks.MCState):  # A = P_Omega(W - M), COO values resid
             return mc_ops.matvec(s.rows, s.cols, s.resid, v, self._base.d, **self._kw)
@@ -351,7 +390,8 @@ class KernelizedTask:
         if isinstance(s, tasks.LogisticState):
             t = pm_ops.matvec(s.x, u, **self._kw)
             p = self._base._probs(s)
-            return p.T @ t - jnp.zeros((self._base.m,), t.dtype).at[s.y].add(t)
+            return (jnp.dot(p.T, t, precision=_F32)
+                    - jnp.zeros((self._base.m,), t.dtype).at[s.y].add(t))
         if isinstance(s, tasks.MCState):
             return mc_ops.rmatvec(s.rows, s.cols, s.resid, u, self._base.m, **self._kw)
         return self._base.rmatvec(s, u)
@@ -374,10 +414,16 @@ def verify_kernelized(
 ) -> float:
     """Assert kernel-routed matvec/rmatvec match the base task's pure-jnp
     operator chain (the same oracle ``kernels/power_matvec/ref.py`` encodes)
-    on random unit probes. Returns the max relative error observed."""
+    on random unit probes. Returns the max relative error observed.
+
+    The oracle runs at ``Precision.HIGHEST``, so on TPU (whose default f32
+    matmul rounds operands to bf16) the check compares the kernels with the
+    f32 maths, not with another precision mode."""
     kv, ku = jax.random.split(key)
     v = sphere_vector(kv, task.m)
     u = sphere_vector(ku, task.d)
+    with jax.default_matmul_precision("highest"):
+        want_v, want_u = task.matvec(state, v), task.rmatvec(state, u)
 
     def rel_err(a, b):
         a, b = jnp.asarray(a), jnp.asarray(b)
@@ -388,8 +434,8 @@ def verify_kernelized(
         ))
 
     err = max(
-        rel_err(ktask.matvec(state, v), task.matvec(state, v)),
-        rel_err(ktask.rmatvec(state, u), task.rmatvec(state, u)),
+        rel_err(ktask.matvec(state, v), want_v),
+        rel_err(ktask.rmatvec(state, u), want_u),
     )
     if err > tol:
         raise AssertionError(
@@ -667,7 +713,7 @@ def fit(
         )
 
     xs, ys = shard_rowwise(mesh, (x, y), cfg.data_axis)
-    state = ktask.init_state(xs, ys)
+    state = init_rowwise_state(ktask, xs, ys, cfg.data_axis)
     it = low_rank.init(max_rank, task.d, task.m)
 
     # Per-worker reducer state: every worker starts from the reducer's own

@@ -8,7 +8,7 @@ Functions (not module constants) so importing never touches device state.
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import numpy as np
@@ -35,8 +35,27 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mes
     return jax.sharding.Mesh(dev, axes)
 
 
-# TPU v5e hardware constants (per chip) — used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
-DCN_BW = 25e9  # B/s per host, assumed for the "pod" axis
+class DevicePeaks(NamedTuple):
+    """Published per-chip peaks: bf16 matmul FLOP/s and HBM bytes/s."""
+
+    flops_bf16: float
+    hbm_bytes_per_s: float
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. Source: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bytes_per_s=819e9),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of one chip of ``device_kind``; an unlisted kind raises rather
+    than borrowing another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind={device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}"
+        ) from None

@@ -24,8 +24,8 @@ Responsibilities:
      hop crosses DCN), ``flat`` single-host. The ``dfw`` subcommand runs a
      distributed DFW-Trace fit with it end to end.
 
-On this CPU container the module is import-safe and the single-host path is
-exercised by the test-suite; the distributed init is only taken when
+Importing the module has no side effects; the single-host path is exercised
+by the test-suite, and the distributed init is only taken when
 --coordinator is given.
 """
 from __future__ import annotations
@@ -35,6 +35,8 @@ import sys
 from typing import Optional
 
 import jax
+
+from .compile_cache import enable_compile_cache
 
 
 def initialize(coordinator: Optional[str], num_hosts: int, host_id: int) -> None:
@@ -122,6 +124,7 @@ def main() -> None:
     ap.add_argument("rest", nargs=argparse.REMAINDER)
     args = ap.parse_args()
 
+    enable_compile_cache()
     initialize(args.coordinator, args.num_hosts, args.host_id)
     if jax.process_index() == 0:
         print(f"[multihost] {jax.process_count()} hosts, "

@@ -31,6 +31,7 @@ from repro.configs import get_config
 from repro.models import lm
 from repro.serve import ServeConfig, ServingEngine
 
+from .compile_cache import enable_compile_cache
 from .steps import make_serve_step
 
 
@@ -185,6 +186,7 @@ def main(argv=None):
     lp.add_argument("--temperature", type=float, default=0.0)
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mode == "factor":
         serve_factored(
             checkpoint=args.checkpoint, max_batch=args.max_batch,

@@ -27,6 +27,7 @@ from repro.models import lm
 from repro.models.config import ModelConfig, ShapeSpec
 from repro.optim import adamw
 
+from .compile_cache import enable_compile_cache
 from .mesh import make_mesh
 from .params import param_pspecs
 from .sharding import use_mesh
@@ -131,6 +132,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
     mesh_shape = tuple(int(x) for x in args.mesh.split("x")) if args.mesh else None
     train(
         arch=args.arch, steps=args.steps, smoke=not args.full,

@@ -275,11 +275,13 @@ class ServingEngine:
                 try:
                     from ..analysis import hlo as hlo_lib
 
-                    info = hlo_lib.analyze(exe.as_text())
+                    text = exe.as_text()
+                    info = hlo_lib.analyze(text)
                     self.telemetry.event(
                         "serve.executable", "serve", capacity=capacity,
                         hlo_flops=info["flops"],
                         hlo_dot_bytes=info["dot_bytes"],
+                        tpu_kernel_calls=hlo_lib.tpu_kernel_calls(text),
                     )
                 except Exception:  # pragma: no cover - HLO formats drift
                     pass
@@ -463,7 +465,11 @@ def verify_factor_kernels(
 ) -> float:
     """Assert the fused factor-matvec path matches the dense materialized
     product on a random triple, in both scoring directions. Returns the max
-    relative error observed; raises AssertionError past ``tol``."""
+    relative error observed; raises AssertionError past ``tol``.
+
+    The dense oracle runs at ``Precision.HIGHEST`` (TPU's default f32 matmul
+    rounds operands to bf16), so the check compares the kernel with the f32
+    maths."""
     from ..kernels.factor_matvec import ref as fm_ref
 
     ks = jax.random.split(key, 5)
@@ -481,11 +487,12 @@ def verify_factor_kernels(
 
     x_d = jax.random.normal(ks[3], (batch, dd))
     x_m = jax.random.normal(ks[4], (batch, mm))
+    with jax.default_matmul_precision("highest"):
+        want_d = fm_ref.dense_matvec(x_d, a, s, b)
+        want_m = fm_ref.dense_matvec(x_m, b, s, a)
     err = max(
-        rel_err(fm_ops.factor_matvec(x_d, a, s, b, **kw),
-                fm_ref.dense_matvec(x_d, a, s, b)),
-        rel_err(fm_ops.factor_matvec(x_m, b, s, a, **kw),
-                fm_ref.dense_matvec(x_m, b, s, a)),
+        rel_err(fm_ops.factor_matvec(x_d, a, s, b, **kw), want_d),
+        rel_err(fm_ops.factor_matvec(x_m, b, s, a, **kw), want_m),
     )
     if err > tol:
         raise AssertionError(

@@ -485,7 +485,7 @@ def test_collective_rounds_contract_subprocess_8way():
         from repro.core import power_method
 
         K, n, m = 2, 256, 32
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         a = jax.ShapeDtypeStruct((n, m), jnp.float32)
         v0 = jax.ShapeDtypeStruct((m,), jnp.float32)
         contract = power_method.collective_rounds_contract(K)

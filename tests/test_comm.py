@@ -134,6 +134,22 @@ def test_verify_quantize_kernels_passes_and_catches():
         comm.verify_quantize_kernels(KEY, num_workers=8, tol=-1.0)
 
 
+def test_verify_quantize_kernels_catches_one_integer_off(monkeypatch):
+    """A quantize kernel that moves a single integer by one (a different
+    rounding of the same value) trips the check."""
+    from repro.comm import int8
+
+    honest = int8.qops.quantize
+
+    def one_off(*args, **kw):
+        q = honest(*args, **kw)
+        return q.at[3].set(jnp.where(q[3] < 0, q[3] + 1, q[3] - 1))
+
+    monkeypatch.setattr(int8.qops, "quantize", one_off)
+    with pytest.raises(AssertionError, match="diverges"):
+        comm.verify_quantize_kernels(KEY, num_workers=8)
+
+
 # ---------------------------------------------------------------------------
 # Quantize kernel trio: interpret-mode Pallas vs jnp ref (exact: same noise)
 # ---------------------------------------------------------------------------
@@ -393,7 +409,7 @@ def test_int8_within_2pct_and_3x_fewer_bytes():
 
         # --- wire bytes: HLO-measured epoch collectives, dense vs int8,
         # at the SAME sizes the convergence runs above used ---
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         K = 2
         x = jax.ShapeDtypeStruct((n, d), jnp.float32)
         y = jax.ShapeDtypeStruct((n, m), jnp.float32)

@@ -172,6 +172,70 @@ def test_uneven_rows_rejected():
     assert "OK" in out
 
 
+def test_shard_rowwise_sends_host_rows_to_their_devices():
+    """A host array goes straight to its row shards, and the state fit
+    builds from it (the logistic Z) is born sharded the same way, with the
+    data passed through uncopied."""
+    out = _run("""
+        import jax, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import tasks
+        from repro.launch import dfw
+
+        mesh = dfw.data_mesh(8)
+        x = np.arange(64 * 3, dtype=np.float32).reshape(64, 3)
+        y = np.arange(64, dtype=np.int32) % 5
+        xs, ys = dfw.shard_rowwise(mesh, (x, y))
+        for arr, host in ((xs, x), (ys, y)):
+            shards = sorted(arr.addressable_shards, key=lambda s: s.device.id)
+            assert len({s.device.id for s in shards}) == 8
+            for s in shards:
+                np.testing.assert_array_equal(np.asarray(s.data), host[s.index])
+                assert s.data.shape[0] == 8
+        task = dfw.kernelize(tasks.MultinomialLogistic(d=3, m=5))
+        st = dfw.init_rowwise_state(task, xs, ys)
+        assert st.x is xs and st.y is ys  # the data is not copied
+        assert st.z.sharding == NamedSharding(mesh, P("data"))
+        assert {s.data.shape for s in st.z.addressable_shards} == {(8, 5)}
+        print("row shards OK")
+    """)
+    assert "OK" in out
+
+
+def test_data_mesh_names_the_platform():
+    with pytest.raises(ValueError, match=r"visible cpu devices=\d+; on CPU "
+                                         r"set XLA_FLAGS"):
+        dfw.data_mesh(len(jax.devices()) + 1)
+
+
+def test_device_peaks_are_keyed_by_kind():
+    from repro.launch import mesh
+
+    peaks = mesh.device_peaks("TPU v5 lite")
+    assert (peaks.flops_bf16, peaks.hbm_bytes_per_s) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        mesh.device_peaks("cpu")
+
+
+def test_compile_cache_is_fixed_or_left_to_the_environment(monkeypatch):
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+    assert compile_cache.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = Path(__file__).resolve().parent.parent
+    assert path == str(root / ".jax_cache")
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+
 # ---------------------------------------------------------------------------
 # Kernel routing (single device; ops dispatch to the jnp ref off-TPU)
 # ---------------------------------------------------------------------------
@@ -233,6 +297,39 @@ def test_verify_kernelized_catches_divergence():
 
     with pytest.raises(AssertionError, match="diverges"):
         dfw.verify_kernelized(task, Broken(task), s, key)
+
+
+def _bf16(a):
+    return a.astype(jnp.bfloat16).astype(a.dtype)
+
+
+@pytest.mark.parametrize("taskcls", [tasks.MultiTaskLeastSquares,
+                                     tasks.MultinomialLogistic])
+def test_verify_kernelized_catches_bf16_operands(taskcls):
+    """A kernel that rounds its operands to bf16 (what TPU's default matmul
+    precision does to f32) must trip the check: its oracle runs at
+    Precision.HIGHEST, so precision loss is not hidden on either side."""
+    n, d, m = 256, 32, 24
+    key = jax.random.PRNGKey(7)
+    task = taskcls(d=d, m=m)
+    y = (jax.random.randint(jax.random.fold_in(key, 1), (n,), 0, m)
+         if taskcls is tasks.MultinomialLogistic
+         else jax.random.normal(jax.random.fold_in(key, 1), (n, m)))
+    s = task.init_state(jax.random.normal(key, (n, d)), y)
+    s = task.update(s, jax.random.normal(jax.random.fold_in(key, 2), (d,)),
+                    jax.random.normal(jax.random.fold_in(key, 3), (m,)),
+                    0.5, 1.0)  # a nonzero iterate: Z/R carry real values
+
+    class Bf16Operands(dfw.KernelizedTask):
+        def matvec(self, st, v):
+            rounded = jax.tree.map(
+                lambda a: _bf16(a) if jnp.issubdtype(a.dtype, jnp.floating)
+                else a, st)
+            return super().matvec(rounded, _bf16(v))
+
+    assert dfw.verify_kernelized(task, dfw.kernelize(task), s, key) < 1e-4
+    with pytest.raises(AssertionError, match="diverges"):
+        dfw.verify_kernelized(task, Bf16Operands(task), s, key)
 
 
 def test_fit_serial_rejects_sample_prob():

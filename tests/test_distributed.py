@@ -40,7 +40,7 @@ def test_sharded_dfw_trace_equals_serial():
                                  key=jax.random.PRNGKey(1), schedule="const:2",
                                  step_size="linesearch")
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         ss = tasks.MTLSState(x=P("data"), y=P("data"), r=P("data"))
         from repro.core import engine
         wrap = engine.shard_map_segment_wrapper(mesh, "data", ss)
@@ -69,7 +69,7 @@ def test_sharded_head_training_and_powersgd():
         W = jax.random.normal(key, (d, m))
         X = jax.random.normal(jax.random.fold_in(key, 1), (n, d))
         y = jnp.argmax(X @ W, axis=1)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         res = dfw_head.sharded_fit(mesh, X, y, m, mu=8.0, num_epochs=25)
         assert res.history["loss"][-1] < 0.7 * res.history["loss"][0]
         err = dfw_head.top_k_error(res.iterate, X, y, k=5)
@@ -105,7 +105,7 @@ def test_seq_sharded_flash_decode():
         from repro.models import layers
         from repro.kernels.flash_attention import ref
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         b, hq, hkv, S, dh = 1, 4, 2, 128, 16
         key = jax.random.PRNGKey(0)
         q = jax.random.normal(key, (b, hq, 1, dh))
@@ -135,7 +135,7 @@ def test_straggler_dropout_still_converges():
         W = jax.random.normal(key, (d, m)); W = W / jnp.linalg.norm(W, ord="nuc")
         X = jax.random.normal(jax.random.fold_in(key, 1), (n, d)); Y = X @ W
         task = tasks.MultiTaskLeastSquares(d=d, m=m)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         ss = tasks.MTLSState(x=P("data"), y=P("data"), r=P("data"))
         isp = low_rank.FactoredIterate(u=P(), s=P(), v=P(), alpha=P(), count=P())
         asp = frank_wolfe.EpochAux(P(), P(), P(), P(), P())
@@ -173,8 +173,8 @@ def test_elastic_checkpoint_remesh():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointStore
 
-        mesh8 = jax.make_mesh((8,), ("data",))
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh8 = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+        mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         x = jnp.arange(64.0).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh8, P("data", None)))
         with tempfile.TemporaryDirectory() as dd:
@@ -205,7 +205,7 @@ def test_moe_ep_shard_map_matches_local():
 
         out_local, aux_local = moe.moe_block(p, x, cfg)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with use_mesh(mesh):
             out_ep, aux_ep = jax.jit(lambda p, x: moe.moe_block(p, x, cfg))(p, x)
         np.testing.assert_allclose(np.asarray(out_local), np.asarray(out_ep),

@@ -109,8 +109,14 @@ def test_linesearch_is_exact_quadratic_minimizer():
 
 
 def test_zero_weight_padding_is_noop():
-    """Padded states must produce bit-identical losses, matvecs and updates —
-    the invariant the shard_map driver's static shapes rest on."""
+    """Padded states must give the same losses, matvecs and updates — the
+    invariant the shard_map driver's static shapes rest on.
+
+    Every padding entry contributes an exact 0 (its residual is 0 and stays
+    0, asserted below), and the matvecs scatter those zeros without changing
+    any row's sum. The losses, though, sum a longer vector, and XLA's
+    reduction tree depends on the length: they agree to a few f32 ulp, not
+    bit for bit."""
     rows, cols, vals, _ = _mc_problem(KEY)
     task = tasks.MatrixCompletion(d=30, m=24)
     s0 = task.init_state(*tasks.pack_observations(rows, cols, vals))
@@ -126,12 +132,13 @@ def test_zero_weight_padding_is_noop():
     u = jax.random.normal(jax.random.fold_in(KEY, 8), (30,))
     np.testing.assert_array_equal(np.asarray(task.matvec(s0, v)),
                                   np.asarray(task.matvec(s1, v)))
-    np.testing.assert_array_equal(float(task.local_loss(s0)),
-                                  float(task.local_loss(s1)))
+    ulps = dict(rtol=4 * np.finfo(np.float32).eps, atol=0)
+    np.testing.assert_allclose(float(task.local_loss(s0)),
+                               float(task.local_loss(s1)), **ulps)
     u, v = u / jnp.linalg.norm(u), v / jnp.linalg.norm(v)
     s0u, s1u = task.update(s0, u, v, 0.5, 1.0), task.update(s1, u, v, 0.5, 1.0)
-    np.testing.assert_array_equal(float(task.local_loss(s0u)),
-                                  float(task.local_loss(s1u)))
+    np.testing.assert_allclose(float(task.local_loss(s0u)),
+                               float(task.local_loss(s1u)), **ulps)
     np.testing.assert_allclose(task.linesearch_terms(s0u, u, v, 1.0),
                                task.linesearch_terms(s1u, u, v, 1.0),
                                rtol=1e-6)

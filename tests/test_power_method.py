@@ -105,8 +105,13 @@ def _reference_power_iterations(matvec, rmatvec, v0, num_iters):
 
 @pytest.mark.parametrize("k", [1, 2, 5])
 def test_carried_sigma_trajectory_unchanged(k):
-    """The 2K-round version must produce the identical (u, v, sigma): the
-    last loop iteration's aggregated rmatvec IS the old post-loop recompute."""
+    """The 2K-round version must produce the same (u, v, sigma): the last
+    loop iteration's aggregated rmatvec IS the old post-loop recompute.
+
+    u and v come out of identical loop bodies and match bit for bit. sigma
+    is the same norm of the same vector, but one version takes it inside
+    the loop body and the other after the loop, and XLA may fuse (and so
+    order) the two reductions differently: it is held to a few f32 ulp."""
     a = jax.random.normal(jax.random.PRNGKey(42), (40, 30))
     v0 = sphere_vector(jax.random.PRNGKey(43), 30)
     got = power_method.power_iterations(
@@ -115,7 +120,8 @@ def test_carried_sigma_trajectory_unchanged(k):
     want = _reference_power_iterations(lambda v: a @ v, lambda u: a.T @ u, v0, k)
     assert np.array_equal(np.asarray(got.u), np.asarray(want.u))
     assert np.array_equal(np.asarray(got.v), np.asarray(want.v))
-    assert np.array_equal(np.asarray(got.sigma), np.asarray(want.sigma))
+    np.testing.assert_allclose(np.asarray(got.sigma), np.asarray(want.sigma),
+                               rtol=4 * np.finfo(np.float32).eps, atol=0)
 
 
 def test_collective_rounds_per_epoch_is_2k():
@@ -137,7 +143,7 @@ def test_collective_rounds_per_epoch_is_2k():
         # Row-shard an explicit (n, m) matrix: each worker holds a (n/8, m)
         # summand A_j, so the implicit operator A = sum_j A_j is (n/8, m).
         K, n, m = 3, 512, 48
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def run(a, v0):
             return power_method.power_iterations(

@@ -297,3 +297,18 @@ def test_verify_factor_kernels_runs_on_first_load_only():
     assert eng._verified
     err = serve.verify_factor_kernels(jax.random.PRNGKey(0), d=D, m=M)
     assert err < 1e-4
+
+
+def test_verify_factor_kernels_catches_bf16_operands(monkeypatch):
+    """A scoring kernel that rounds its request batch to bf16 trips the
+    check: the dense oracle runs at Precision.HIGHEST."""
+    from repro.serve import engine as serve_engine
+
+    honest = serve_engine.fm_ops.factor_matvec
+
+    def bf16_requests(x, *args, **kw):
+        return honest(x.astype(jnp.bfloat16).astype(x.dtype), *args, **kw)
+
+    monkeypatch.setattr(serve_engine.fm_ops, "factor_matvec", bf16_requests)
+    with pytest.raises(AssertionError, match="diverge"):
+        serve.verify_factor_kernels(jax.random.PRNGKey(0), d=D, m=M)
