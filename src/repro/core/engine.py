@@ -34,6 +34,7 @@ measures against.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
@@ -398,6 +399,7 @@ def run_epochs(
     num_workers: int = 1,
     solver="rank1",
     probe: Optional[PyTree] = None,
+    end_setup: Optional[Callable[[], None]] = None,
 ) -> EngineResult:
     """Run up to ``num_epochs`` DFW-Trace epochs, device-resident.
 
@@ -432,6 +434,9 @@ def run_epochs(
     the full-run values — the plan is recomputed from ``start_t`` and the
     same executables re-dispatch, reproducing the uninterrupted trajectory
     bit-for-bit (pinned in ``tests/test_checkpoint_resume.py``).
+
+    ``end_setup`` is called once, just before the first segment dispatch
+    (``launch/dfw.fit`` closes its ``engine.setup`` span with it).
 
     **Telemetry.** ``telemetry`` (``repro.obs.Telemetry``; inert no-op when
     None) records compile/dispatch/segment spans, per-executable comm cost
@@ -531,10 +536,9 @@ def run_epochs(
                 # jax.jit is kept on the non-HLO path because its call cache
                 # is independent of lower().compile() — mixing them would
                 # compile twice.
-                t0 = tel.now_us()
-                exe = jitted.lower(*args).compile()
-                tel.complete("engine.compile", "engine", t0,
-                             tel.now_us() - t0, k=seg.k, length=seg.length)
+                with tel.span("engine.compile", "engine", k=seg.k,
+                              length=seg.length):
+                    exe = jitted.lower(*args).compile()
                 _emit_executable_cost(seg, exe)
                 compiled[sig] = exe
             else:
@@ -629,6 +633,8 @@ def run_epochs(
         return host_masks_cache[0]
 
     if mode == "legacy":
+        if end_setup is not None:
+            end_setup()
         # Pre-engine behavior: one dispatch + four blocking float() pulls
         # per epoch (each an implicit device->host transfer, like the old
         # driver's `float(aux.loss)` lines). Boundaries are every epoch, so
@@ -686,10 +692,10 @@ def run_epochs(
 
     def _record_block(idx: int, t_end_us: float) -> None:
         """Telemetry for a block whose host aux just landed: the segment
-        span (dispatch -> data on host), the comm-exchange span with
-        analytic byte accounting, and per-epoch scalar samples timestamped
-        by linear interpolation across the span. Pure bookkeeping on
-        already-fetched host values — no device access."""
+        span (dispatch -> data on host), the analytic comm byte counters,
+        and per-epoch scalar samples timestamped by linear interpolation
+        across the span. Pure bookkeeping on already-fetched host values —
+        no device access."""
         if idx in recorded or not tel.enabled:
             return
         recorded.add(idx)
@@ -699,27 +705,14 @@ def run_epochs(
         tel.complete("engine.segment", "engine", t0, dur,
                      start=seg.start, length=seg.length, k=seg.k)
         cost = _comm_cost(seg)
-        tel.complete("comm.exchange", "comm", t0, dur,
-                     spec=getattr(reducer, "spec", None),
-                     num_workers=num_workers, **cost)
         reg = tel.registry
         reg.counter("comm.rounds").inc(cost["rounds"])
         reg.counter("comm.logical_bytes").inc(cost["logical_bytes"])
         reg.counter("comm.wire_bytes").inc(cost["wire_bytes"])
-        hops = _hop_cost(seg)
-        if hops:
-            # Topology-mode accounting: one span naming the graph plus a
-            # per-hop byte counter split (comm.hop_bytes.intra/inter/...).
-            tel.complete(
-                "comm.topology", "comm", t0, dur,
-                topology=getattr(reducer, "spec", None),
-                rounds_per_exchange=int(
-                    getattr(reducer, "rounds_per_exchange", 1)
-                ),
-                **{f"bytes_{h}": b for h, b in sorted(hops.items())},
-            )
-            for h, b in hops.items():
-                reg.counter(f"comm.hop_bytes.{h}").inc(b)
+        # Topology-mode accounting: a per-hop byte counter split
+        # (comm.hop_bytes.intra/inter/...).
+        for h, b in _hop_cost(seg).items():
+            reg.counter(f"comm.hop_bytes.{h}").inc(b)
         if sspec.kind == "block":
             reg.gauge("dfw.block.k").set(k_block)
         for j in range(seg.length):
@@ -741,12 +734,18 @@ def run_epochs(
 
     for i, seg in enumerate(segments):
         args = (carry, done, nrun) + ((masks,) if has_masks else ())
+        # Without HLO capture a new signature is traced, and compiled or
+        # loaded from the persistent cache, inside its first call.
+        compiles = (seg.k, seg.length) not in compiled and not tel.wants_hlo
         exe = get_compiled(seg, args)
+        if i == 0 and end_setup is not None:
+            end_setup()
         t_disp = tel.now_us()
-        carry, done, nrun, aux = exe(*args)
-        tel.complete("engine.dispatch", "engine", t_disp,
-                     tel.now_us() - t_disp, start=seg.start,
-                     length=seg.length, k=seg.k)
+        with tel.span("engine.dispatch", "engine", t0=t_disp, start=seg.start,
+                      length=seg.length, k=seg.k):
+            with (tel.span("engine.compile", "engine", k=seg.k, length=seg.length)
+                  if compiles else contextlib.nullcontext()):
+                carry, done, nrun, aux = exe(*args)
         stats["dispatches"] += 1
         stats["segments_run"] += 1
         host_aux = None

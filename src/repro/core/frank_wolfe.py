@@ -255,61 +255,69 @@ def make_epoch_step(
         ekey = jax.random.fold_in(carry.key, ti)
         ckey = jax.random.fold_in(ekey, 0xC033)
         w = 1.0 if worker_weight is None else worker_weight
-        loss = _psum(w * task.local_loss(state), axis_name)
-        inner = _psum(w * task.inner_w_grad(state), axis_name)
+        # Named scopes mark each phase in the HLO metadata, so the device
+        # trace splits an epoch's time into loss, power, exchange, line
+        # search and update (docs/OBSERVABILITY.md).
+        with jax.named_scope("dfw.loss"):
+            loss = _psum(w * task.local_loss(state), axis_name)
+            inner = _psum(w * task.inner_w_grad(state), axis_name)
 
         if sspec.kind == "block":
             k = sspec.k
-            # Fresh random columns every epoch; the carried probe (when warm)
-            # replaces them entirely. Mixing a small random component back in
-            # would also work but breaks block:1 == rank1 equivalence.
-            rand0 = jnp.stack(
-                [sphere_vector(jax.random.fold_in(ekey, 101 + j), task.m)
-                 for j in range(k)],
-                axis=1,
-            )
-            if sspec.cold or not isinstance(carry.probe, jax.Array):
-                v0 = rand0
-            else:
-                # Warm start from last epoch's converged right block; any
-                # numerically dead column (all-zero from init skeletons)
-                # falls back to its random column.
-                col_norm = jnp.linalg.norm(carry.probe, axis=0, keepdims=True)
-                v0 = jnp.where(col_norm > 1e-6, carry.probe, rand0)
-            res, comm_state = block_power_iterations(
-                partial(task.matvec, state),
-                partial(task.rmatvec, state),
-                v0,
-                num_power_iters,
-                axis_name=axis_name,
-                worker_weight=worker_weight,
-                reducer=reducer,
-                comm_state=carry.comm_state,
-                key=ckey,
-                adapt_rtol=ADAPT_RTOL if sspec.adaptive else None,
-                # Scale for "did refinement stop mattering": the gap
-                # certificate is inner + mu*sigma_max, so changes small
-                # relative to |inner|/mu (or sigma itself) can't move it.
-                adapt_ref=jnp.abs(inner) / mu,
-            )
-            sigma_max = jnp.max(res.sigma)
-            gap = duality_gap(inner, sigma_max, mu)
-            # Blend the k atoms into one feasible direction
-            # S = -mu sum_j c_j u_j v_j^T with c = sigma / sum(sigma):
-            # the trace-ball-normalized top-k projection of -grad
-            # (sum c = 1 keeps ||S||_* <= mu). Fold c into u's columns so
-            # tasks see the same (u, v) signature as rank1.
-            c = res.sigma / (jnp.sum(res.sigma) + 1e-30)
-            u_c = res.u * c[None, :]
-            if step_size == "linesearch":
-                numer, denom = task.linesearch_terms(state, u_c, res.v, mu)
-                numer = _psum(w * numer, axis_name)
-                denom = _psum(w * denom, axis_name)
-                gamma = jnp.clip(numer / jnp.maximum(denom, 1e-30), 0.0, 1.0)
-            else:
-                gamma = 2.0 / (t + 2.0)
-            state = task.update(state, u_c, res.v, gamma, mu)
-            it = low_rank.fw_update_block(it, res.u, res.v, c, gamma, mu)
+            with jax.named_scope("dfw.power"):
+                # Fresh random columns every epoch; the carried probe (when
+                # warm) replaces them entirely. Mixing a small random
+                # component back in would also work but breaks block:1 ==
+                # rank1 equivalence.
+                rand0 = jnp.stack(
+                    [sphere_vector(jax.random.fold_in(ekey, 101 + j), task.m)
+                     for j in range(k)],
+                    axis=1,
+                )
+                if sspec.cold or not isinstance(carry.probe, jax.Array):
+                    v0 = rand0
+                else:
+                    # Warm start from last epoch's converged right block; any
+                    # numerically dead column (all-zero from init skeletons)
+                    # falls back to its random column.
+                    col_norm = jnp.linalg.norm(carry.probe, axis=0, keepdims=True)
+                    v0 = jnp.where(col_norm > 1e-6, carry.probe, rand0)
+                res, comm_state = block_power_iterations(
+                    partial(task.matvec, state),
+                    partial(task.rmatvec, state),
+                    v0,
+                    num_power_iters,
+                    axis_name=axis_name,
+                    worker_weight=worker_weight,
+                    reducer=reducer,
+                    comm_state=carry.comm_state,
+                    key=ckey,
+                    adapt_rtol=ADAPT_RTOL if sspec.adaptive else None,
+                    # Scale for "did refinement stop mattering": the gap
+                    # certificate is inner + mu*sigma_max, so changes small
+                    # relative to |inner|/mu (or sigma itself) can't move it.
+                    adapt_ref=jnp.abs(inner) / mu,
+                )
+                sigma_max = jnp.max(res.sigma)
+                gap = duality_gap(inner, sigma_max, mu)
+                # Blend the k atoms into one feasible direction
+                # S = -mu sum_j c_j u_j v_j^T with c = sigma / sum(sigma):
+                # the trace-ball-normalized top-k projection of -grad
+                # (sum c = 1 keeps ||S||_* <= mu). Fold c into u's columns
+                # so tasks see the same (u, v) signature as rank1.
+                c = res.sigma / (jnp.sum(res.sigma) + 1e-30)
+                u_c = res.u * c[None, :]
+            with jax.named_scope("dfw.linesearch"):
+                if step_size == "linesearch":
+                    numer, denom = task.linesearch_terms(state, u_c, res.v, mu)
+                    numer = _psum(w * numer, axis_name)
+                    denom = _psum(w * denom, axis_name)
+                    gamma = jnp.clip(numer / jnp.maximum(denom, 1e-30), 0.0, 1.0)
+                else:
+                    gamma = 2.0 / (t + 2.0)
+            with jax.named_scope("dfw.update"):
+                state = task.update(state, u_c, res.v, gamma, mu)
+                it = low_rank.fw_update_block(it, res.u, res.v, c, gamma, mu)
             aux = EpochAux(
                 loss=loss, gap=gap, sigma=sigma_max, gamma=gamma,
                 piters=res.iters.astype(jnp.float32),
@@ -319,40 +327,43 @@ def make_epoch_step(
                 t=ti + 1, key=carry.key, probe=res.probe,
             ), aux
 
-        v0 = sphere_vector(ekey, task.m)
-        res, comm_state = power_iterations(
-            partial(task.matvec, state),
-            partial(task.rmatvec, state),
-            v0,
-            num_power_iters,
-            axis_name=axis_name,
-            worker_weight=worker_weight,
-            reducer=reducer,
-            comm_state=carry.comm_state,
-            key=ckey,
-        )
+        with jax.named_scope("dfw.power"):
+            v0 = sphere_vector(ekey, task.m)
+            res, comm_state = power_iterations(
+                partial(task.matvec, state),
+                partial(task.rmatvec, state),
+                v0,
+                num_power_iters,
+                axis_name=axis_name,
+                worker_weight=worker_weight,
+                reducer=reducer,
+                comm_state=carry.comm_state,
+                key=ckey,
+            )
 
-        gap = duality_gap(inner, res.sigma, mu)
-        sigma = res.sigma
-        if per_node:
-            # inner/loss are exact global psums (already replicated); only
-            # the gossip-estimated sigma — and hence the gap — differs per
-            # node. pmax makes both replicated: the recorded gap upper-bounds
-            # every node's certificate, so early stop fires only when ALL
-            # nodes are within tol.
-            gap = _pmax(gap, axis_name)
-            sigma = _pmax(sigma, axis_name)
+            gap = duality_gap(inner, res.sigma, mu)
+            sigma = res.sigma
+            if per_node:
+                # inner/loss are exact global psums (already replicated);
+                # only the gossip-estimated sigma — and hence the gap —
+                # differs per node. pmax makes both replicated: the recorded
+                # gap upper-bounds every node's certificate, so early stop
+                # fires only when ALL nodes are within tol.
+                gap = _pmax(gap, axis_name)
+                sigma = _pmax(sigma, axis_name)
 
-        if step_size == "linesearch":
-            numer, denom = task.linesearch_terms(state, res.u, res.v, mu)
-            numer = _psum(w * numer, axis_name)
-            denom = _psum(w * denom, axis_name)
-            gamma = jnp.clip(numer / jnp.maximum(denom, 1e-30), 0.0, 1.0)
-        else:
-            gamma = 2.0 / (t + 2.0)
+        with jax.named_scope("dfw.linesearch"):
+            if step_size == "linesearch":
+                numer, denom = task.linesearch_terms(state, res.u, res.v, mu)
+                numer = _psum(w * numer, axis_name)
+                denom = _psum(w * denom, axis_name)
+                gamma = jnp.clip(numer / jnp.maximum(denom, 1e-30), 0.0, 1.0)
+            else:
+                gamma = 2.0 / (t + 2.0)
 
-        state = task.update(state, res.u, res.v, gamma, mu)
-        it = low_rank.fw_update(it, res.u, res.v, gamma, mu)
+        with jax.named_scope("dfw.update"):
+            state = task.update(state, res.u, res.v, gamma, mu)
+            it = low_rank.fw_update(it, res.u, res.v, gamma, mu)
         aux = EpochAux(
             loss=loss, gap=gap, sigma=sigma, gamma=gamma,
             piters=jnp.full((), num_power_iters, jnp.float32),

@@ -26,6 +26,20 @@ AxisName = Optional[Union[str, Sequence[str]]]
 _EPS = 1e-30
 
 
+def _exchange(reducer, local: jax.Array, cs, **kw):
+    """``reducer.exchange`` under the ``comm.exchange`` name scope, so that
+    the device trace names the collective apart from the local product
+    (computed by the caller, outside the scope)."""
+    with jax.named_scope("comm.exchange"):
+        return reducer.exchange(local, cs, **kw)
+
+
+def _aggregate(local: jax.Array, axis_name: AxisName) -> jax.Array:
+    """The exact-psum aggregate under the ``comm.exchange`` name scope."""
+    with jax.named_scope("comm.exchange"):
+        return _psum(local, axis_name)
+
+
 class PowerResult(NamedTuple):
     """Top singular triple estimate after K two-sided power iterations."""
 
@@ -168,9 +182,9 @@ def power_iterations(
 
         def body(_, carry):
             _, v, _ = carry
-            u = _psum(w * matvec(v), axis_name)
+            u = _aggregate(w * matvec(v), axis_name)
             u = u / (jnp.linalg.norm(u) + _EPS)
-            vv = _psum(w * rmatvec(u), axis_name)
+            vv = _aggregate(w * rmatvec(u), axis_name)
             nv = jnp.linalg.norm(vv)
             v = vv / (nv + _EPS)
             return (u, v, nv)
@@ -189,14 +203,14 @@ def power_iterations(
         # worker_weight rides along so stateful reducers can tell a masked
         # worker (whose w*matvec is zero but whose residual is not) from a
         # live one — see comm/base.Reducer.exchange.
-        uu, cs = reducer.exchange(
-            w * matvec(v), cs, slot="u",
+        uu, cs = _exchange(
+            reducer, w * matvec(v), cs, slot="u",
             key=jax.random.fold_in(ki, 0), axis_name=axis_name,
             weight=worker_weight,
         )
         u = uu / (jnp.linalg.norm(uu) + _EPS)
-        vv, cs = reducer.exchange(
-            w * rmatvec(u), cs, slot="v",
+        vv, cs = _exchange(
+            reducer, w * rmatvec(u), cs, slot="v",
             key=jax.random.fold_in(ki, 1), axis_name=axis_name,
             weight=worker_weight,
         )
@@ -318,14 +332,14 @@ def block_power_iterations(
     def live(i, c):
         _, v, _, sigma, cs, done, iters = c
         ki = jax.random.fold_in(key, i)
-        uu, cs = reducer.exchange(
-            (w * matmat(v)).reshape(-1), cs, slot="u",
+        uu, cs = _exchange(
+            reducer, (w * matmat(v)).reshape(-1), cs, slot="u",
             key=jax.random.fold_in(ki, 0), axis_name=axis_name,
             weight=worker_weight,
         )
         ub = orthonormalize_block(uu.reshape(d, k))
-        vv, cs = reducer.exchange(
-            (w * rmatmat(ub)).reshape(-1), cs, slot="v",
+        vv, cs = _exchange(
+            reducer, (w * rmatmat(ub)).reshape(-1), cs, slot="v",
             key=jax.random.fold_in(ki, 1), axis_name=axis_name,
             weight=worker_weight,
         )

@@ -40,6 +40,7 @@ Typical use (8 simulated hosts; see ``examples/distributed_dfw.py``)::
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Callable, Dict, Optional
@@ -658,201 +659,215 @@ def fit(
     segments each compiled as one ``lax.scan`` inside ``shard_map``, epochs
     advancing entirely on device. ``callback(start_t, aux_block)`` fires per
     segment (see ``frank_wolfe.fit``), not per epoch.
+
+    With ``cfg.telemetry`` the call is one job (``Telemetry.job``), bracketed
+    whole by ``Telemetry.profiler``. Its top-level spans cover it:
+    ``engine.setup`` (entry to the first segment dispatch; children
+    ``engine.verify_kernels`` and ``engine.place``), then the engine's
+    ``engine.dispatch`` and ``engine.fetch``, then ``engine.final_loss``.
     """
-    if mesh is None:
-        if num_workers is None:
-            raise ValueError("pass mesh= or num_workers=")
-        mesh = data_mesh(num_workers, cfg.data_axis)
-    elif num_workers is not None and mesh.shape[cfg.data_axis] != num_workers:
-        raise ValueError(
-            f"mesh has {mesh.shape[cfg.data_axis]} workers on "
-            f"{cfg.data_axis!r} but num_workers={num_workers}; pass one or "
-            "make them agree"
-        )
-    nw = mesh.shape[cfg.data_axis]
-    sspec, _, tspec = specs.validate(
-        solver=cfg.solver, comm=cfg.comm, topology=cfg.topology
-    )
-    k_block = sspec.k if sspec.kind == "block" else 1
-    max_rank = engine.resolve_max_rank(cfg.max_rank, cfg.num_epochs, k_block)
     tel = cfg.telemetry if cfg.telemetry is not None else Telemetry.noop()
-    tel.event("run.start", "run", driver="launch.dfw.fit",
-              task=type(task).__name__, d=int(task.d), m=int(task.m),
-              num_workers=nw, comm=cfg.comm, topology=cfg.topology,
-              schedule=cfg.schedule,
-              num_epochs=cfg.num_epochs, solver=cfg.solver)
-
-    # The comm stack: a Topology (exchange graph) wrapping a Reducer (wire
-    # encoding). "flat" hands the bare reducer to the engine — the exact
-    # legacy psum path, bit for bit — while ring/gossip/hier pass the
-    # topology itself (it quacks like a Reducer: same ``exchange``
-    # signature, so nothing downstream changes shape).
-    topo = comm_lib.make_topology(
-        cfg.topology, num_workers=nw, comm=cfg.comm,
-        rounds=cfg.gossip_rounds,
-        use_pallas=cfg.use_pallas, interpret=cfg.interpret,
-    )
-    reducer = topo.reducer
-    comm_obj = reducer if tspec.kind == "flat" else topo
-    per_node = bool(getattr(comm_obj, "per_node", False))
-
-    ktask = (
-        kernelize(task, use_pallas=cfg.use_pallas, interpret=cfg.interpret)
-        if cfg.kernelize
-        else task
-    )
-    if cfg.kernelize and cfg.verify_kernels:
-        # Probe on a small host-local slice before committing to the run.
-        probe_rows = min(x.shape[0], max(nw, 64))
-        probe = task.init_state(x[:probe_rows], y[:probe_rows])
-        verify_kernelized(task, ktask, probe, jax.random.fold_in(key, 0x5EED))
-    if isinstance(reducer, comm_lib.Int8Reducer) and cfg.verify_kernels:
-        comm_lib.verify_quantize_kernels(
-            jax.random.fold_in(key, 0x17F8),
-            num_workers=nw, use_pallas=cfg.use_pallas, interpret=cfg.interpret,
-        )
-
-    xs, ys = shard_rowwise(mesh, (x, y), cfg.data_axis)
-    state = init_rowwise_state(ktask, xs, ys, cfg.data_axis)
-    it = low_rank.init(max_rank, task.d, task.m)
-
-    # Per-worker reducer state: every worker starts from the reducer's own
-    # init_state values (not zeros — the contract allows nonzero
-    # initialization), stacked along a leading worker axis sharded like the
-    # data rows. Dense's () has no leaves, so this is a no-op there. The
-    # block solver flattens (d,k)/(m,k) blocks through the reducer, so
-    # stateful encodings are sized for the flattened payload.
-    comm_example = reducer.init_state(task.d * k_block, task.m * k_block)
-    comm_state = jax.tree.map(
-        lambda leaf: jax.device_put(
-            jnp.broadcast_to(leaf, (nw,) + leaf.shape),
-            NamedSharding(mesh, P(cfg.data_axis)),
-        ),
-        comm_example,
-    )
-
-    # Block-solver warm-start probe: replicated (m, k) block, cold-started
-    # deterministically (() for rank1 — zero extra carry leaves).
-    probe_blk = frank_wolfe.init_probe(sspec, task.m)
-    if sspec.kind == "block":
-        probe_blk = jax.device_put(probe_blk, NamedSharding(mesh, P()))
-
-    sampling = cfg.sample_prob < 1.0
-    if sampling:
-        masks = worker_schedule(
-            jax.random.fold_in(key, 0x1A5C),
-            cfg.num_epochs,
-            nw,
-            cfg.sample_prob,
-            reweight=cfg.reweight,
-        )
-    else:
-        masks = jnp.ones((cfg.num_epochs, nw), jnp.float32)
-
-    start_t, initial_history = 0, None
-    if cfg.resume_from is not None:
-        # `state` (freshly built above) supplies the treedef skeleton; its
-        # values are then replaced wholesale by the checkpointed ones.
-        snap = ckpt.restore_run(
-            cfg.resume_from, state_like=state, step=cfg.resume_step
-        )
-        _check_snapshot(snap, task, cfg)
-        state = shard_rowwise(mesh, snap.carry.state, cfg.data_axis)
-        it = snap.unpack_iterate(max_rank)
-        key = jnp.asarray(snap.carry.key)
-        start_t, initial_history = snap.t, snap.history
-        snap_probe = getattr(snap.carry, "probe", ())
-        if (
-            sspec.kind == "block"
-            and hasattr(snap_probe, "shape")
-            and tuple(snap_probe.shape) == (task.m, sspec.k)
-        ):
-            # v2 checkpoint with a matching block width: resume the warm
-            # start bit-exactly. v1 payloads (or a changed k) keep the cold
-            # probe initialized above — convergence is preserved, warmth
-            # is not.
-            probe_blk = jax.device_put(
-                jnp.asarray(snap_probe), NamedSharding(mesh, P())
+    # The body stays inline under this ``with``. A version that ran it in a
+    # helper called from here lowered the segment's scan 70-90 ms slower
+    # per job on a v5e host (+7-9 % time to gap for the ImageNet head, with
+    # the telemetry off). A plain pass-through frame did not reproduce it;
+    # the cause is open (PERF.md, Findings and Open questions).
+    with tel.job(), tel.profiler(), contextlib.ExitStack() as setup:
+        setup.enter_context(tel.span("engine.setup", "engine"))
+        if mesh is None:
+            if num_workers is None:
+                raise ValueError("pass mesh= or num_workers=")
+            mesh = data_mesh(num_workers, cfg.data_axis)
+        elif num_workers is not None and mesh.shape[cfg.data_axis] != num_workers:
+            raise ValueError(
+                f"mesh has {mesh.shape[cfg.data_axis]} workers on "
+                f"{cfg.data_axis!r} but num_workers={num_workers}; pass one or "
+                "make them agree"
             )
-        same_mesh = int(snap.extra.get("num_workers", -1)) == nw
-        same_topo = snap.extra.get("topology", "flat") == cfg.topology
-        if same_mesh and same_topo and snap.extra.get("comm") == reducer.spec:
-            # Bit-exact path: per-worker reducer state (e.g. top-k
-            # error-feedback residuals) resumes exactly where it stopped.
+        nw = mesh.shape[cfg.data_axis]
+        sspec, _, tspec = specs.validate(
+            solver=cfg.solver, comm=cfg.comm, topology=cfg.topology
+        )
+        k_block = sspec.k if sspec.kind == "block" else 1
+        max_rank = engine.resolve_max_rank(cfg.max_rank, cfg.num_epochs, k_block)
+        tel.event("run.start", "run", driver="launch.dfw.fit",
+                  task=type(task).__name__, d=int(task.d), m=int(task.m),
+                  num_workers=nw, comm=cfg.comm, topology=cfg.topology,
+                  schedule=cfg.schedule,
+                  num_epochs=cfg.num_epochs, solver=cfg.solver)
+
+        # The comm stack: a Topology (exchange graph) wrapping a Reducer (wire
+        # encoding). "flat" hands the bare reducer to the engine — the exact
+        # legacy psum path, bit for bit — while ring/gossip/hier pass the
+        # topology itself (it quacks like a Reducer: same ``exchange``
+        # signature, so nothing downstream changes shape).
+        topo = comm_lib.make_topology(
+            cfg.topology, num_workers=nw, comm=cfg.comm,
+            rounds=cfg.gossip_rounds,
+            use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+        )
+        reducer = topo.reducer
+        comm_obj = reducer if tspec.kind == "flat" else topo
+        per_node = bool(getattr(comm_obj, "per_node", False))
+
+        ktask = (
+            kernelize(task, use_pallas=cfg.use_pallas, interpret=cfg.interpret)
+            if cfg.kernelize
+            else task
+        )
+        with tel.span("engine.verify_kernels", "engine"):
+            if cfg.kernelize and cfg.verify_kernels:
+                # Probe on a small host-local slice before committing to the run.
+                probe_rows = min(x.shape[0], max(nw, 64))
+                probe = task.init_state(x[:probe_rows], y[:probe_rows])
+                verify_kernelized(task, ktask, probe, jax.random.fold_in(key, 0x5EED))
+            if isinstance(reducer, comm_lib.Int8Reducer) and cfg.verify_kernels:
+                comm_lib.verify_quantize_kernels(
+                    jax.random.fold_in(key, 0x17F8),
+                    num_workers=nw, use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+                )
+
+        with tel.span("engine.place", "engine"):
+            xs, ys = shard_rowwise(mesh, (x, y), cfg.data_axis)
+            state = init_rowwise_state(ktask, xs, ys, cfg.data_axis)
+            it = low_rank.init(max_rank, task.d, task.m)
+
+            # Per-worker reducer state: every worker starts from the reducer's own
+            # init_state values (not zeros — the contract allows nonzero
+            # initialization), stacked along a leading worker axis sharded like the
+            # data rows. Dense's () has no leaves, so this is a no-op there. The
+            # block solver flattens (d,k)/(m,k) blocks through the reducer, so
+            # stateful encodings are sized for the flattened payload.
+            comm_example = reducer.init_state(task.d * k_block, task.m * k_block)
             comm_state = jax.tree.map(
                 lambda leaf: jax.device_put(
-                    jnp.asarray(leaf), NamedSharding(mesh, P(cfg.data_axis))
+                    jnp.broadcast_to(leaf, (nw,) + leaf.shape),
+                    NamedSharding(mesh, P(cfg.data_axis)),
                 ),
-                snap.carry.comm_state,
-            )
-        # else: keep the freshly initialized comm_state — an elastic remesh
-        # (or a warm comm-mode change) re-derives per-worker state.
-        same_sampling = (
-            float(snap.extra.get("sample_prob", -1.0)) == cfg.sample_prob
-            and bool(snap.extra.get("reweight", not cfg.reweight))
-            == cfg.reweight
-        )
-        if (
-            same_mesh
-            and same_sampling
-            and snap.masks is not None
-            and snap.masks.shape == (cfg.num_epochs, nw)
-        ):
-            masks = jnp.asarray(snap.masks)
-        # else: the regenerated schedule above stands — a new worker count
-        # or extended num_epochs re-draws it, and a warm restart that
-        # changes sample_prob/reweight must get the schedule it asked for,
-        # not the checkpointed run's.
-        if _resume_complete(snap, cfg):
-            # Nothing left to run: the checkpoint already holds the final
-            # carry (epoch budget spent, or its gap certificate still
-            # stands under this config's gap_tol).
-            final_loss = float(jax.device_get(jax.jit(ktask.local_loss)(state)))
-            return DFWFitResult(
-                iterate=it, state=state, history=snap.history,
-                masks=masks[: snap.t] if sampling else None,
-                final_loss=final_loss, epochs_run=snap.t,
-                stats={"segments_planned": 0, "segments_run": 0,
-                       "dispatches": 1, "compilations": 1, "host_syncs": 1},
+                comm_example,
             )
 
-    checkpointer = _make_checkpointer(
-        task, cfg, nw, reducer.spec, tel, per_node_iterate=per_node
-    )
-    if checkpointer is not None:
-        # checkpoint_dir belongs to THIS run's timeline from here on: a
-        # fresh run clears any previous run's steps, a resume keeps its
-        # prefix and drops the abandoned tail. Either way, steps past
-        # start_t would shadow this run's history on the next default
-        # (latest-step) resume.
-        checkpointer.store.discard_after(start_t)
+            # Block-solver warm-start probe: replicated (m, k) block, cold-started
+            # deterministically (() for rank1 — zero extra carry leaves).
+            probe_blk = frank_wolfe.init_probe(sspec, task.m)
+            if sspec.kind == "block":
+                probe_blk = jax.device_put(probe_blk, NamedSharding(mesh, P()))
 
-    if per_node:
-        # Gossip: every worker evolves its own inexact-consensus iterate, so
-        # the (possibly resumed node-0) iterate is stacked along a leading
-        # worker axis sharded like the data rows — the exact treatment the
-        # per-worker reducer state already gets. A gossip resume is elastic
-        # here by construction: checkpoints store the node-0 slice and this
-        # broadcast re-seeds every node with it (the optimization dynamics
-        # themselves resume bit-exactly — they read only the task state).
-        it = jax.tree.map(
-            lambda leaf: jax.device_put(
-                jnp.broadcast_to(leaf, (nw,) + leaf.shape),
-                NamedSharding(mesh, P(cfg.data_axis)),
-            ),
-            it,
+            sampling = cfg.sample_prob < 1.0
+            if sampling:
+                masks = worker_schedule(
+                    jax.random.fold_in(key, 0x1A5C),
+                    cfg.num_epochs,
+                    nw,
+                    cfg.sample_prob,
+                    reweight=cfg.reweight,
+                )
+            else:
+                masks = jnp.ones((cfg.num_epochs, nw), jnp.float32)
+
+        start_t, initial_history = 0, None
+        if cfg.resume_from is not None:
+            # `state` (freshly built above) supplies the treedef skeleton; its
+            # values are then replaced wholesale by the checkpointed ones.
+            snap = ckpt.restore_run(
+                cfg.resume_from, state_like=state, step=cfg.resume_step
+            )
+            _check_snapshot(snap, task, cfg)
+            state = shard_rowwise(mesh, snap.carry.state, cfg.data_axis)
+            it = snap.unpack_iterate(max_rank)
+            key = jnp.asarray(snap.carry.key)
+            start_t, initial_history = snap.t, snap.history
+            snap_probe = getattr(snap.carry, "probe", ())
+            if (
+                sspec.kind == "block"
+                and hasattr(snap_probe, "shape")
+                and tuple(snap_probe.shape) == (task.m, sspec.k)
+            ):
+                # v2 checkpoint with a matching block width: resume the warm
+                # start bit-exactly. v1 payloads (or a changed k) keep the cold
+                # probe initialized above — convergence is preserved, warmth
+                # is not.
+                probe_blk = jax.device_put(
+                    jnp.asarray(snap_probe), NamedSharding(mesh, P())
+                )
+            same_mesh = int(snap.extra.get("num_workers", -1)) == nw
+            same_topo = snap.extra.get("topology", "flat") == cfg.topology
+            if same_mesh and same_topo and snap.extra.get("comm") == reducer.spec:
+                # Bit-exact path: per-worker reducer state (e.g. top-k
+                # error-feedback residuals) resumes exactly where it stopped.
+                comm_state = jax.tree.map(
+                    lambda leaf: jax.device_put(
+                        jnp.asarray(leaf), NamedSharding(mesh, P(cfg.data_axis))
+                    ),
+                    snap.carry.comm_state,
+                )
+            # else: keep the freshly initialized comm_state — an elastic remesh
+            # (or a warm comm-mode change) re-derives per-worker state.
+            same_sampling = (
+                float(snap.extra.get("sample_prob", -1.0)) == cfg.sample_prob
+                and bool(snap.extra.get("reweight", not cfg.reweight))
+                == cfg.reweight
+            )
+            if (
+                same_mesh
+                and same_sampling
+                and snap.masks is not None
+                and snap.masks.shape == (cfg.num_epochs, nw)
+            ):
+                masks = jnp.asarray(snap.masks)
+            # else: the regenerated schedule above stands — a new worker count
+            # or extended num_epochs re-draws it, and a warm restart that
+            # changes sample_prob/reweight must get the schedule it asked for,
+            # not the checkpointed run's.
+            if _resume_complete(snap, cfg):
+                # Nothing left to run: the checkpoint already holds the final
+                # carry (epoch budget spent, or its gap certificate still
+                # stands under this config's gap_tol).
+                final_loss = float(jax.device_get(jax.jit(ktask.local_loss)(state)))
+                return DFWFitResult(
+                    iterate=it, state=state, history=snap.history,
+                    masks=masks[: snap.t] if sampling else None,
+                    final_loss=final_loss, epochs_run=snap.t,
+                    stats={"segments_planned": 0, "segments_run": 0,
+                           "dispatches": 1, "compilations": 1, "host_syncs": 1},
+                )
+
+        checkpointer = _make_checkpointer(
+            task, cfg, nw, reducer.spec, tel, per_node_iterate=per_node
         )
+        if checkpointer is not None:
+            # checkpoint_dir belongs to THIS run's timeline from here on: a
+            # fresh run clears any previous run's steps, a resume keeps its
+            # prefix and drops the abandoned tail. Either way, steps past
+            # start_t would shadow this run's history on the next default
+            # (latest-step) resume.
+            checkpointer.store.discard_after(start_t)
 
-    wrapper = engine.shard_map_segment_wrapper(
-        mesh,
-        cfg.data_axis,
-        row_specs(state, cfg.data_axis),
-        comm_state_example=comm_example,
-        probe_example=probe_blk,
-        has_masks=True,
-        per_node_iterate=per_node,
-    )
-    with tel.profiler():
+        if per_node:
+            # Gossip: every worker evolves its own inexact-consensus iterate, so
+            # the (possibly resumed node-0) iterate is stacked along a leading
+            # worker axis sharded like the data rows — the exact treatment the
+            # per-worker reducer state already gets. A gossip resume is elastic
+            # here by construction: checkpoints store the node-0 slice and this
+            # broadcast re-seeds every node with it (the optimization dynamics
+            # themselves resume bit-exactly — they read only the task state).
+            it = jax.tree.map(
+                lambda leaf: jax.device_put(
+                    jnp.broadcast_to(leaf, (nw,) + leaf.shape),
+                    NamedSharding(mesh, P(cfg.data_axis)),
+                ),
+                it,
+            )
+
+        wrapper = engine.shard_map_segment_wrapper(
+            mesh,
+            cfg.data_axis,
+            row_specs(state, cfg.data_axis),
+            comm_state_example=comm_example,
+            probe_example=probe_blk,
+            has_masks=True,
+            per_node_iterate=per_node,
+        )
         eres = engine.run_epochs(
             ktask,
             state,
@@ -878,38 +893,39 @@ def fit(
             num_workers=nw,
             solver=sspec,
             probe=probe_blk if sspec.kind == "block" else None,
+            end_setup=setup.close,
         )
-    if checkpointer is not None:
-        # Surface the last in-flight write's failure here, not silently at
-        # interpreter exit — the run result should not claim durability the
-        # store never achieved.
-        with tel.span("checkpoint.join", "checkpoint"):
-            checkpointer.wait()
-    # Loss at the returned iterate (history is pre-update; see frank_wolfe.fit).
-    # The plain sum over the row-sharded state is already the global loss, and
-    # straggler weights never apply here: this is the true full-data F.
-    with tel.span("engine.final_loss", "engine"):
-        final_loss = float(
-            jax.device_get(jax.jit(ktask.local_loss)(eres.carry.state))
+        if checkpointer is not None:
+            # Surface the last in-flight write's failure here, not silently at
+            # interpreter exit — the run result should not claim durability the
+            # store never achieved.
+            with tel.span("checkpoint.join", "checkpoint"):
+                checkpointer.wait()
+        # Loss at the returned iterate (history is pre-update; see frank_wolfe.fit).
+        # The plain sum over the row-sharded state is already the global loss, and
+        # straggler weights never apply here: this is the true full-data F.
+        with tel.span("engine.final_loss", "engine"):
+            final_loss = float(
+                jax.device_get(jax.jit(ktask.local_loss)(eres.carry.state))
+            )
+        eres.stats["dispatches"] += 1
+        eres.stats["host_syncs"] += 1
+        eres.stats["compilations"] += 1
+        it_out = eres.carry.iterate
+        if per_node:
+            # Report node 0's iterate — the same convention gossip checkpoints
+            # use. All nodes agree to consensus tolerance; the caller's
+            # final_loss above is the exact full-data F of the *states*.
+            it_out = jax.tree.map(lambda a: a[0], it_out)
+        return DFWFitResult(
+            iterate=it_out,
+            state=eres.carry.state,
+            history=eres.history,
+            masks=masks[: eres.epochs_run] if sampling else None,
+            final_loss=final_loss,
+            epochs_run=eres.epochs_run,
+            stats=eres.stats,
         )
-    eres.stats["dispatches"] += 1
-    eres.stats["host_syncs"] += 1
-    eres.stats["compilations"] += 1
-    it_out = eres.carry.iterate
-    if per_node:
-        # Report node 0's iterate — the same convention gossip checkpoints
-        # use. All nodes agree to consensus tolerance; the caller's
-        # final_loss above is the exact full-data F of the *states*.
-        it_out = jax.tree.map(lambda a: a[0], it_out)
-    return DFWFitResult(
-        iterate=it_out,
-        state=eres.carry.state,
-        history=eres.history,
-        masks=masks[: eres.epochs_run] if sampling else None,
-        final_loss=final_loss,
-        epochs_run=eres.epochs_run,
-        stats=eres.stats,
-    )
 
 
 def fit_serial(

@@ -22,6 +22,19 @@ Events are stored in Chrome trace-event form (ph "X" complete spans,
 "i" instants, "C" counter samples) so both sinks serialize the same
 dicts; timestamps are microseconds from the handle's creation
 (``time.perf_counter`` based — monotonic, sub-us resolution).
+
+Causality: every span and instant records in ``args`` the name of the
+innermost context span open on its thread (``parent``; absent at the top
+level) and the id of the job it belongs to (``job``; one per
+``launch/dfw.fit`` call, see :meth:`Telemetry.job`). A layer's self time is
+its span minus the part its children cover.
+
+One clock with the device trace: when ``profiler_dir`` is set, every
+context span is also written to the profiler's host plane as a
+``jax.profiler.TraceAnnotation`` of the same name. The offset from this
+handle's clock to the profiler's is the profiler start of a job's
+``engine.setup`` annotation minus that span's ``ts``; adding it places the
+retroactive events (``engine.segment``, ``jit.*``) on the device timeline.
 """
 from __future__ import annotations
 
@@ -53,9 +66,11 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager emitting one ph="X" complete event on exit."""
+    """Context manager emitting one ph="X" complete event on exit. While
+    open it is the ``parent`` of what its thread records, and with a
+    ``profiler_dir`` it is also a profiler annotation."""
 
-    __slots__ = ("_tel", "_name", "_cat", "_t0", "_args")
+    __slots__ = ("_tel", "_name", "_cat", "_t0", "_args", "_parent", "_anno")
 
     def __init__(self, tel: "Telemetry", name: str, cat: str,
                  t0: Optional[float], args: Dict[str, Any]):
@@ -64,16 +79,47 @@ class _Span:
         self._cat = cat
         self._t0 = t0
         self._args = args
+        self._anno = None
 
     def __enter__(self):
+        tel = self._tel
+        if tel.profiler_dir:
+            import jax
+
+            self._anno = jax.profiler.TraceAnnotation(self._name)
+            self._anno.__enter__()
+        stack = tel._stack()
+        self._parent = stack[-1]._name if stack else None
+        stack.append(self)
         if self._t0 is None:
-            self._t0 = self._tel.now_us()
+            self._t0 = tel.now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self._tel.complete(self._name, self._cat, self._t0,
-                           self._tel.now_us() - self._t0, **self._args)
+        tel = self._tel
+        t1 = tel.now_us()
+        tel._stack().remove(self)
+        tel._record_x(self._name, self._cat, self._t0, t1 - self._t0,
+                      self._parent, self._args)
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc, tb)
         return False
+
+
+# jax.monitoring events a job listens to (``Telemetry.job``). Each timed
+# compile phase becomes a retroactive span and a seconds counter; a backend
+# compile (an executable built by XLA or loaded from the persistent cache)
+# also counts one ``jit.compiles``.
+_JIT_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jit.trace", "jit.trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("jit.lower", "jit.lower_s"),
+    "/jax/core/compile/backend_compile_duration": ("jit.compile", "jit.compile_s"),
+}
+_JIT_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "jit.cache_hits",
+}
+_JIT_COUNTERS = ("jit.compiles", *_JIT_COUNTS.values(),
+                *(c for _, c in _JIT_PHASES.values()))
 
 
 class Telemetry:
@@ -97,7 +143,8 @@ class Telemetry:
     profiler_dir:
         When set, ``profiler()`` brackets the run with
         ``jax.profiler.start_trace/stop_trace`` writing XLA-level data
-        there; when None the hook is a no-op.
+        there, and every context span is also a profiler annotation; when
+        None the hook is a no-op.
     """
 
     def __init__(self, enabled: bool = True, *, capture_hlo: bool = True,
@@ -114,6 +161,10 @@ class Telemetry:
         self._pid = os.getpid()
         self._t0_perf = time.perf_counter()
         self._t0_unix = time.time()
+        self._local = threading.local()  # per-thread stack of open spans
+        self._job: Optional[int] = None
+        self._jobs = 0
+        self._jit_depth = 0  # compile phases open (``job``)
 
     # -- time ---------------------------------------------------------------
 
@@ -139,6 +190,30 @@ class Telemetry:
             with self._lock:
                 self._dropped += 1
 
+    def _stack(self) -> List[_Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _tagged(self, args: Dict[str, Any], parent: Optional[str]) -> Dict[str, Any]:
+        if parent is not None:
+            args["parent"] = parent
+        if self._job is not None:
+            args["job"] = self._job
+        return args
+
+    def _current_parent(self) -> Optional[str]:
+        stack = self._stack()
+        return stack[-1]._name if stack else None
+
+    def _record_x(self, name: str, cat: str, ts_us: float, dur_us: float,
+                  parent: Optional[str], args: Dict[str, Any]) -> None:
+        self._append({"name": name, "cat": cat, "ph": "X",
+                      "ts": round(ts_us, 3), "dur": round(max(dur_us, 0.0), 3),
+                      "pid": self._pid, "tid": threading.get_ident(),
+                      "args": self._tagged(args, parent)})
+
     def span(self, name: str, cat: str = "run",
              t0: Optional[float] = None, **args: Any):
         """Context manager producing a complete ("X") event on exit.
@@ -154,13 +229,13 @@ class Telemetry:
 
     def complete(self, name: str, cat: str, ts_us: float, dur_us: float,
                  **args: Any) -> None:
-        """Record a retroactive complete span [ts_us, ts_us + dur_us]."""
+        """Record a retroactive complete span [ts_us, ts_us + dur_us]. Its
+        ``parent`` is the context span open on this thread now. It never
+        reaches the profiler's trace (see the module docstring for the
+        offset that places it there)."""
         if not self.enabled:
             return
-        self._append({"name": name, "cat": cat, "ph": "X",
-                      "ts": round(ts_us, 3), "dur": round(max(dur_us, 0.0), 3),
-                      "pid": self._pid, "tid": threading.get_ident(),
-                      "args": args})
+        self._record_x(name, cat, ts_us, dur_us, self._current_parent(), args)
 
     def event(self, name: str, cat: str = "run",
               ts_us: Optional[float] = None, **args: Any) -> None:
@@ -170,7 +245,7 @@ class Telemetry:
         self._append({"name": name, "cat": cat, "ph": "i", "s": "t",
                       "ts": round(self.now_us() if ts_us is None else ts_us, 3),
                       "pid": self._pid, "tid": threading.get_ident(),
-                      "args": args})
+                      "args": self._tagged(args, self._current_parent())})
 
     def counter_sample(self, name: str, value: float, cat: str = "metrics",
                        ts_us: Optional[float] = None) -> None:
@@ -190,6 +265,80 @@ class Telemetry:
         """Copy of the event stream (Chrome trace-event dicts)."""
         with self._lock:
             return list(self._events)
+
+    # -- jobs and JAX's compile events --------------------------------------
+
+    def job(self):
+        """Context manager around one job (a ``launch/dfw.fit`` call): the
+        events recorded inside carry its id as ``job``, and JAX's compile
+        events are listened to through ``jax.monitoring`` until it exits.
+
+        They become the counters ``jit.compiles`` (executables built by XLA
+        or loaded from the persistent cache), ``jit.cache_hits``,
+        ``jit.trace_s``, ``jit.lower_s`` and ``jit.compile_s``, and the
+        retroactive spans ``jit.trace``, ``jit.lower`` and ``jit.compile``
+        (with ``fun_name``). Only a phase that starts while no other is open is
+        counted and recorded: the jitted helpers traced inside a trace or a
+        lowering are part of it. The listeners are process-wide, so a
+        compile on another thread during the job counts too. A disabled
+        handle registers nothing."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return self._job_scope()
+
+    @contextmanager
+    def _job_scope(self):
+        from jax import monitoring
+
+        self._jobs += 1
+        outer_job, self._job = self._job, self._jobs
+        outer_depth, self._jit_depth = self._jit_depth, 0
+        for name in _JIT_COUNTERS:  # present, at 0, in every job's snapshot
+            self.registry.counter(name)
+        listeners = (
+            # JAX records a phase's start as a scalar and its end as a time
+            # span: the pair gives the depth of nested phases.
+            (monitoring.register_scalar_listener,
+             monitoring.unregister_scalar_listener, self._on_jit_start),
+            (monitoring.register_event_time_span_listener,
+             monitoring.unregister_event_time_span_listener, self._on_jit_span),
+            (monitoring.register_event_listener,
+             monitoring.unregister_event_listener, self._on_jit_event),
+        )
+        for register, _, cb in listeners:
+            register(cb)
+        try:
+            yield self._job
+        finally:
+            for _, unregister, cb in listeners:
+                unregister(cb)
+            self._job, self._jit_depth = outer_job, outer_depth
+
+    def _on_jit_start(self, event: str, value: float, **kw) -> None:
+        if event in _JIT_PHASES:
+            self._jit_depth += 1
+
+    def _on_jit_span(self, event: str, start: float, end: float, **kw) -> None:
+        names = _JIT_PHASES.get(event)
+        if names is None:
+            return
+        self._jit_depth = max(self._jit_depth - 1, 0)
+        if self._jit_depth:  # inside an outer phase, which counts it
+            return
+        span_name, seconds = names
+        # start/end are time.time() readings: convert at this instant, so
+        # that the wall clock's drift from perf_counter does not enter.
+        ts = self.now_us() - (time.time() - start) * 1e6
+        self.complete(span_name, "jit", ts, (end - start) * 1e6,
+                      fun_name=kw.get("fun_name"))
+        self.registry.counter(seconds).inc(end - start)
+        if span_name == "jit.compile":
+            self.registry.counter("jit.compiles").inc()
+
+    def _on_jit_event(self, event: str, **kw) -> None:
+        name = _JIT_COUNTS.get(event)
+        if name is not None:
+            self.registry.counter(name).inc()
 
     # -- jax.profiler hook --------------------------------------------------
 

@@ -261,7 +261,9 @@ def test_serial_const2_pin_holds_with_telemetry_enabled():
     np.testing.assert_array_equal(np.asarray(res.history["loss"]),
                                   np.asarray(base.history["loss"]))
     names = {ev["name"] for ev in tel.events()}
-    assert {"engine.segment", "engine.dispatch", "comm.exchange"} <= names
+    assert {"engine.segment", "engine.dispatch"} <= names
+    counters = tel.registry.snapshot()["counters"]
+    assert counters["comm.rounds"] == 2 * 2 * 30  # 2K exchanges an epoch
 
 
 def test_log_schedule_is_olog_dispatches():
@@ -417,3 +419,23 @@ def test_sharded8_scan_equals_legacy_all_tasks():
         print("equivalence matrix OK")
     """, timeout=1200)
     assert "equivalence matrix OK" in out
+
+
+@pytest.mark.parametrize("solver", ["rank1", "block:2"])
+def test_compiled_epoch_names_the_solver_phases_in_its_hlo_metadata(solver):
+    """The named scopes reach the compiled epoch as op_name metadata, which
+    the device trace carries into each operation's name stack."""
+    from repro.core import low_rank
+
+    x, y = _mtls(jax.random.PRNGKey(11))
+    task = tasks.MultiTaskLeastSquares(d=24, m=18)
+    state = task.init_state(x, y)
+    carry = frank_wolfe.init_carry(
+        state, low_rank.init(8, 24, 18), jax.random.PRNGKey(1),
+        (), probe=frank_wolfe.init_probe(frank_wolfe.parse_solver(solver), 18))
+    epoch = frank_wolfe.make_epoch_step(task, 1.0, 2, step_size="linesearch",
+                                        reducer=comm.DenseReducer(),
+                                        solver=solver)
+    text = jax.jit(epoch).lower(carry).compile().as_text()
+    for scope in ("dfw.loss", "dfw.power", "dfw.linesearch", "dfw.update"):
+        assert f"/{scope}/" in text, scope

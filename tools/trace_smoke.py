@@ -10,8 +10,9 @@ What it proves (`make trace-smoke`, also run by the CI bench-smoke job):
 * ``TRACE_smoke.trace.json`` is Chrome-trace/Perfetto-loadable (every
   event carries name/ph/ts/pid, ph in {X, i, C}, complete spans have
   nonnegative durations);
-* the span stream covers engine segments, reducer exchanges, checkpoint
-  writes, and serving dispatches — one name per instrumented layer.
+* the span stream covers engine segments, checkpoint writes, and serving
+  dispatches, and the registry counts the comm layer's reducer traffic —
+  one name per instrumented layer.
 
 Exit 0 on success, 1 with a reason on any failure.
 """
@@ -24,10 +25,11 @@ from pathlib import Path
 
 REQUIRED_SPANS = (
     "engine.segment",    # engine: one per scan segment
-    "comm.exchange",     # comm: the segment's reducer traffic
     "checkpoint.write",  # checkpoint: async boundary saves
     "serve.dispatch",    # serving: scored batches
 )
+# comm: the reducer traffic, counted analytically per segment
+REQUIRED_COUNTERS = ("comm.rounds", "comm.wire_bytes")
 
 
 def run_instrumented(tmp: Path):
@@ -105,9 +107,15 @@ def main() -> int:
     if missing:
         print(f"trace-smoke: FAIL — missing spans {missing}; got {sorted(names)}")
         return 1
+    counters = tel.registry.snapshot()["counters"]
+    missing = [c for c in REQUIRED_COUNTERS if not counters.get(c)]
+    if missing:
+        print(f"trace-smoke: FAIL — counters {missing} missing or zero")
+        return 1
     print(
         f"trace-smoke: OK — {len(events)} events, {res.epochs_run} epochs, "
-        f"spans cover {', '.join(REQUIRED_SPANS)}; wrote {out_jsonl} + {out_trace}"
+        f"spans cover {', '.join(REQUIRED_SPANS)}, counters "
+        f"{', '.join(REQUIRED_COUNTERS)}; wrote {out_jsonl} + {out_trace}"
     )
     return 0
 
