@@ -33,6 +33,7 @@ below.
 """
 from __future__ import annotations
 
+import dataclasses
 import warnings
 from typing import Any, List, Optional, Sequence, Union
 
@@ -161,6 +162,7 @@ def fold_axis_index(key: jax.Array, axis_name: AxisName) -> jax.Array:
     return key
 
 
+@dataclasses.dataclass(frozen=True)
 class DenseReducer(Reducer):
     """Exact f32 psum — byte-for-byte the paper's master aggregate.
 

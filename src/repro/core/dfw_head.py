@@ -133,7 +133,7 @@ def sharded_fit(
             # The checkpoint already covers the requested budget (the final
             # boundary is always saved): return it rather than asking the
             # engine for zero epochs.
-            final_loss = float(jax.device_get(jax.jit(task.local_loss)(state)))
+            final_loss, _ = engine.final_loss(task, state)
             return HeadFitResult(iterate=iterate, history=resume.history,
                                  final_loss=final_loss)
     if checkpointer is not None:

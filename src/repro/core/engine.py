@@ -20,6 +20,13 @@ keeps whole runs on device:
    in the segment is a ``lax.cond`` no-op (static shapes preserved, compute
    skipped), and the host stops launching segments at the next boundary.
    ``epochs_run`` counts the epochs that actually executed.
+4. **Program reuse.** The jitted segment programs are kept in a bounded,
+   process-wide cache keyed on the values they close over (task, μ,
+   (K, length), step rule, reducer, solver, wrapper, gap tolerance) and the
+   arguments' shapes and shardings, so a later run with equal settings
+   dispatches the executables an earlier run built: no trace, no lowering,
+   no persistent-cache load. ``launch/dfw.py`` keeps its per-job programs
+   in the same cache (``cached_program``).
 
 Host transfers happen only at segment boundaries (and only when early
 stopping or a callback needs them) plus one final history fetch — all via
@@ -34,9 +41,11 @@ measures against.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import math
+import threading
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
@@ -144,7 +153,9 @@ def dispatch_contract(
     over ``segments`` planned segments costs at most ``segments + 1`` jitted
     dispatches (one scan per segment + the driver's final-loss eval), at
     most O(1) explicit host syncs, and — under ``contract.guard()`` — zero
-    implicit device->host transfers. ``max_compilations`` defaults to 2
+    implicit device->host transfers. ``max_compilations`` bounds the
+    distinct executables the run dispatches, whether built in this run or
+    reused from the program cache (``cached_program``); it defaults to 2
     (the single-segment ``const:K`` case: one scan executable + the final
     loss eval); pass ``None`` for schedules whose distinct (K, length)
     signature count isn't pinned. Consumed by ``tests/test_engine.py`` (the
@@ -170,8 +181,10 @@ class EngineResult:
     - ``segments_planned`` / ``segments_run``: plan size vs segments
       actually launched (early stop skips the tail),
     - ``dispatches``: jitted calls issued,
-    - ``compilations``: distinct executables built (segments sharing a
-      (K, length) shape reuse one),
+    - ``compilations``: distinct executables the run dispatches, built or
+      reused (segments sharing a (K, length) shape share one),
+    - ``programs_reused``: those of them found in the process's program
+      cache (``cached_program``), built by an earlier run,
     - ``host_syncs``: explicit ``jax.device_get`` round-trips (legacy mode
       counts its four blocking per-epoch scalar pulls here).
     """
@@ -180,6 +193,82 @@ class EngineResult:
     history: Dict[str, list]
     epochs_run: int
     stats: Dict[str, int]
+
+
+# Jitted programs kept across runs. A key holds the values a program closes
+# over, never the identity of an object rebuilt per job, so a run with equal
+# settings gets the jax.jit object an earlier run built and JAX runs its
+# compiled executable without tracing or lowering again. Entries hold code
+# only: no cached callable captures a run's state, data or iterate.
+_PROGRAM_CACHE_SIZE = 32
+_programs: "collections.OrderedDict[Any, Any]" = collections.OrderedDict()
+_programs_lock = threading.Lock()  # builds run outside it
+
+
+def cached_program(key, build: Callable[[], Any],
+                   telemetry: Optional[Telemetry] = None) -> tuple:
+    """``(program, hit)``: the program cached under ``key``, or ``build()``
+    stored there (least recently used entries past 32 are dropped). A key
+    that cannot be hashed is never stored: its program is built every time.
+    Each lookup adds one to the enabled ``telemetry``'s
+    ``engine.program_cache.hits`` or ``engine.program_cache.misses``."""
+    try:
+        with _programs_lock:
+            program = _programs.get(key)
+            if program is not None:
+                _programs.move_to_end(key)
+    except TypeError:
+        key = program = None
+    hit = program is not None
+    if not hit:
+        program = build()
+        if key is not None:
+            with _programs_lock:
+                _programs[key] = program
+                while len(_programs) > _PROGRAM_CACHE_SIZE:
+                    _programs.popitem(last=False)
+    if telemetry is not None and telemetry.enabled:
+        name = "hits" if hit else "misses"
+        telemetry.registry.counter(f"engine.program_cache.{name}").inc()
+    return program, hit
+
+
+def clear_programs() -> None:
+    """Drop every cached program: the next run builds its own."""
+    with _programs_lock:
+        _programs.clear()
+
+
+def code_of(task, reducer=None) -> tuple:
+    """What the classes of ``task`` and ``reducer`` define that can be
+    called (and of what they wrap: a kernelized task's ``base``, a
+    topology's ``reducer``). A program key holds it beside the objects'
+    values, so a method patched on a class (as the benchmark's fault
+    harness patches ``update``) gives a program traced with the new code."""
+    objs = (task, getattr(task, "base", None),
+            reducer, getattr(reducer, "reducer", None))
+    return tuple(v for obj in objs for cls in type(obj).__mro__[:-1]
+                 for v in vars(cls).values() if callable(v))
+
+
+def final_loss(task, state: PyTree, telemetry: Optional[Telemetry] = None) -> tuple:
+    """``(F, reused)``: ``task.local_loss`` of ``state`` (on row-sharded
+    state the plain sum is already the global loss), by the jitted loss kept
+    in the program cache, and whether that program was reused."""
+    loss_fn, reused = cached_program(
+        ("final_loss", task, code_of(task)), lambda: jax.jit(task.local_loss),
+        telemetry,
+    )
+    return float(jax.device_get(loss_fn(state))), reused
+
+
+def _arg_signature(args: tuple) -> tuple:
+    """What a compiled executable is specialised to: the arguments' tree,
+    types and shardings (no array is held)."""
+    leaves, treedef = jax.tree.flatten(args)
+    return treedef, tuple(
+        (jax.typeof(x), getattr(x, "sharding", None)) for x in leaves
+    )
 
 
 def _segment_step(
@@ -314,7 +403,7 @@ def shard_map_segment_wrapper(
     probe_example: PyTree = (),
     has_masks: bool = False,
     per_node_iterate: bool = False,
-) -> Callable[[Callable], Callable]:
+) -> "ShardMapSegmentWrapper":
     """Build the canonical ``segment_wrapper``: shard_map with the task
     state row-sharded, iterate/scalars/key/probe replicated, straggler masks
     column-sharded, and reducer state carried with a leading worker axis
@@ -322,35 +411,53 @@ def shard_map_segment_wrapper(
     ``per_node_iterate`` extends that leading-axis treatment to the factored
     iterate (gossip topologies; see ``sharded_carry_spec``).
     """
-    from jax.sharding import PartitionSpec as P
-
-    ax = axis_or_axes
     carry_spec = sharded_carry_spec(
-        ax, state_spec, comm_state_example, probe_example,
+        axis_or_axes, state_spec, comm_state_example, probe_example,
         per_node_iterate=per_node_iterate,
     )
-    aux_spec = EpochAux(P(), P(), P(), P(), P())
+    leaves, treedef = jax.tree.flatten(carry_spec)
+    return ShardMapSegmentWrapper(
+        mesh, axis_or_axes, treedef, tuple(leaves), has_masks, per_node_iterate
+    )
 
-    def wrap(seg_fn):
+
+@dataclasses.dataclass(frozen=True)
+class ShardMapSegmentWrapper:
+    """``shard_map_segment_wrapper``'s result: a value (equal wrappers wrap
+    alike), so the program cache can key on it. The carry's PartitionSpecs
+    are held flattened, which keeps them hashable."""
+
+    mesh: Any
+    axes: Any
+    carry_tree: Any
+    carry_leaves: tuple
+    has_masks: bool = False
+    per_node_iterate: bool = False
+
+    def __call__(self, seg_fn: Callable) -> Callable:
+        from jax.sharding import PartitionSpec as P
+
+        per_node = self.per_node_iterate
+        carry_spec = jax.tree.unflatten(self.carry_tree, self.carry_leaves)
+        aux_spec = EpochAux(P(), P(), P(), P(), P())
+
         def step(carry, done, epochs_run, *masks):
             carry, done, epochs_run, aux = seg_fn(
-                strip_worker_axis(carry, per_node_iterate=per_node_iterate),
+                strip_worker_axis(carry, per_node_iterate=per_node),
                 done, epochs_run, *masks
             )
             return (
-                restore_worker_axis(carry, per_node_iterate=per_node_iterate),
+                restore_worker_axis(carry, per_node_iterate=per_node),
                 done, epochs_run, aux,
             )
 
-        mask_specs = (P(None, ax),) if has_masks else ()
+        mask_specs = (P(None, self.axes),) if self.has_masks else ()
         return shard_map_compat(
             step,
-            mesh,
+            self.mesh,
             in_specs=(carry_spec, P(), P()) + mask_specs,
             out_specs=(carry_spec, P(), P(), aux_spec),
         )
-
-    return wrap
 
 
 _HISTORY_KEYS = ("loss", "gap", "sigma", "gamma")
@@ -512,39 +619,53 @@ def run_epochs(
         "segments_run": 0,
         "dispatches": 0,
         "compilations": 0,
+        "programs_reused": 0,
         "host_syncs": 0,
     }
     has_masks = masks is not None
     wrapper = segment_wrapper if segment_wrapper is not None else (lambda f: f)
     tel = telemetry if telemetry is not None else Telemetry.noop()
+    # Everything a segment program closes over, but its (K, length).
+    program_key = ("segment", task, mu, step_size, axis_name, reducer, gap_tol,
+                   has_masks, sspec, segment_wrapper, tel.wants_hlo,
+                   code_of(task, reducer))
 
     compiled: Dict[tuple, Callable] = {}
 
-    def get_compiled(seg: Segment, args: tuple) -> Callable:
+    def get_compiled(seg: Segment, args: tuple) -> tuple:
+        """``(program, built)``: the run's program for ``seg``'s (K, length)
+        and whether this lookup built it (a miss of the program cache)."""
         sig = (seg.k, seg.length)
-        if sig not in compiled:
+        if sig in compiled:
+            return compiled[sig], False
+
+        def build():
             fn = _segment_step(
                 task, mu, seg.k, seg.length,
                 step_size=step_size, axis_name=axis_name, reducer=reducer,
                 gap_tol=gap_tol, has_masks=has_masks, solver=sspec,
             )
             jitted = jax.jit(wrapper(fn))
-            if tel.wants_hlo:
-                # Ahead-of-time compile so the post-SPMD HLO is in hand for
-                # the one-time comm walk; the executable itself dispatches,
-                # so the compile is still counted (and paid) exactly once.
-                # jax.jit is kept on the non-HLO path because its call cache
-                # is independent of lower().compile() — mixing them would
-                # compile twice.
-                with tel.span("engine.compile", "engine", k=seg.k,
-                              length=seg.length):
-                    exe = jitted.lower(*args).compile()
-                _emit_executable_cost(seg, exe)
-                compiled[sig] = exe
-            else:
-                compiled[sig] = jitted
-            stats["compilations"] += 1
-        return compiled[sig]
+            if not tel.wants_hlo:
+                return jitted
+            # Ahead-of-time compile so the post-SPMD HLO is in hand for the
+            # one-time comm walk; the executable itself dispatches, so the
+            # compile is still counted (and paid) exactly once. jax.jit is
+            # kept on the non-HLO path because its call cache is independent
+            # of lower().compile() — mixing them would compile twice.
+            with tel.span("engine.compile", "engine", k=seg.k,
+                          length=seg.length):
+                exe = jitted.lower(*args).compile()
+            _emit_executable_cost(seg, exe)
+            return exe
+
+        program, hit = cached_program(
+            program_key + (sig, _arg_signature(args)), build, tel
+        )
+        compiled[sig] = program
+        stats["compilations"] += 1
+        stats["programs_reused"] += hit
+        return program, not hit
 
     def _emit_executable_cost(seg: Segment, exe) -> None:
         """One HLO walk per executable (never per step): wire-level
@@ -643,7 +764,7 @@ def run_epochs(
         for i, seg in enumerate(segments):
             args = (carry, done, nrun) + ((masks,) if has_masks else ())
             t_disp = tel.now_us()
-            carry, done, nrun, aux = get_compiled(seg, args)(*args)
+            carry, done, nrun, aux = get_compiled(seg, args)[0](*args)
             stats["dispatches"] += 1
             stats["segments_run"] += 1
             row = [float(aux.loss[0]), float(aux.gap[0]),
@@ -734,10 +855,10 @@ def run_epochs(
 
     for i, seg in enumerate(segments):
         args = (carry, done, nrun) + ((masks,) if has_masks else ())
-        # Without HLO capture a new signature is traced, and compiled or
-        # loaded from the persistent cache, inside its first call.
-        compiles = (seg.k, seg.length) not in compiled and not tel.wants_hlo
-        exe = get_compiled(seg, args)
+        # Without HLO capture a program built in this process is traced, and
+        # compiled or loaded from the persistent cache, inside its first call.
+        exe, built = get_compiled(seg, args)
+        compiles = built and not tel.wants_hlo
         if i == 0 and end_setup is not None:
             end_setup()
         t_disp = tel.now_us()
@@ -808,18 +929,21 @@ def run_epochs(
         fetched, epochs_run = jax.device_get((pending, nrun))
     stats["host_syncs"] += 1
     t_final = tel.now_us()
-    epochs_run = int(epochs_run)
-    it = iter(fetched)
-    aux_blocks = [
-        (seg, h if h is not None else next(it), a) for seg, h, a in aux_blocks
-    ]
-    for j in range(len(aux_blocks)):
-        _record_block(j, t_final)
-    history = _assemble_history(history, aux_blocks, epochs_run)
-    if gap_tol is not None and epochs_run < num_epochs:
-        gap_hist = history.get("gap") or [float("nan")]
-        tel.event("engine.early_stop", "engine", epoch=epochs_run,
-                  gap=gap_hist[-1], gap_tol=gap_tol)
+    # Host work on the fetched rows: the history, and the telemetry's
+    # per-epoch samples, which are most of it when it is on.
+    with tel.span("engine.history", "engine"):
+        epochs_run = int(epochs_run)
+        it = iter(fetched)
+        aux_blocks = [
+            (seg, h if h is not None else next(it), a) for seg, h, a in aux_blocks
+        ]
+        for j in range(len(aux_blocks)):
+            _record_block(j, t_final)
+        history = _assemble_history(history, aux_blocks, epochs_run)
+        if gap_tol is not None and epochs_run < num_epochs:
+            gap_hist = history.get("gap") or [float("nan")]
+            tel.event("engine.early_stop", "engine", epoch=epochs_run,
+                      gap=gap_hist[-1], gap_tol=gap_tol)
     return EngineResult(
         carry=carry, history=history, epochs_run=epochs_run, stats=stats
     )

@@ -490,7 +490,7 @@ def fit(
     checkpoint (``None`` cold-starts deterministically); an epoch appends
     k factors, so ``max_rank`` defaults to ``num_epochs * k``.
     """
-    from .engine import run_epochs  # local import: engine builds on this module
+    from .engine import final_loss, run_epochs  # engine builds on this module
     from ..obs import Telemetry
 
     tel = telemetry if telemetry is not None else Telemetry.noop()
@@ -528,17 +528,18 @@ def fit(
     # Loss at the *returned* iterate (cheap: one O(n_j) reduction outside the
     # epoch; on sharded state the plain sum is already the global loss).
     with tel.span("engine.final_loss", "engine"):
-        final_loss = float(jax.device_get(jax.jit(task.local_loss)(eres.carry.state)))
+        loss, reused = final_loss(task, eres.carry.state, tel)
     eres.stats["dispatches"] += 1
     eres.stats["host_syncs"] += 1
     eres.stats["compilations"] += 1
+    eres.stats["programs_reused"] += reused
     if tel.enabled:
-        tel.registry.gauge("dfw.final_loss").set(final_loss)
+        tel.registry.gauge("dfw.final_loss").set(loss)
     return FitResult(
         iterate=eres.carry.iterate,
         state=eres.carry.state,
         history=eres.history,
-        final_loss=final_loss,
+        final_loss=loss,
         epochs_run=eres.epochs_run,
         stats=eres.stats,
     )

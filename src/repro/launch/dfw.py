@@ -246,30 +246,38 @@ def shard_rowwise(mesh: Mesh, tree: PyTree, axis: str = "data") -> PyTree:
     return jax.tree.map(place, tree)
 
 
-def init_rowwise_state(task, xs: jax.Array, ys: jax.Array, axis: str = "data"):
+def init_rowwise_state(task, xs: jax.Array, ys: jax.Array, axis: str = "data",
+                       telemetry=None):
     """``task.init_state`` on row-sharded data, without copying the data.
 
     Leaves the task creates (e.g. the logistic Z) are built under jit with
     row-sharded outputs, so each is born on its workers' devices. Leaves
     that are the data itself are handed back as they are: a jit output
-    would be a second copy of X."""
-    data, which, treedef = (xs, ys), {}, []
+    would be a second copy of X. The jitted program is kept in the engine's
+    program cache (``engine.cached_program``), keyed on the task, mesh and
+    axis; ``telemetry`` counts the lookup."""
+    mesh = xs.sharding.mesh
 
-    def created(x, y):
-        leaves, tdef = jax.tree.flatten(task.init_state(x, y))
-        treedef.append(tdef)
-        for i, leaf in enumerate(leaves):
-            for j, arg in enumerate((x, y)):
-                if leaf is arg:
-                    which[i] = j
-        return [leaf for i, leaf in enumerate(leaves) if i not in which]
+    def build():
+        layout = {}  # the state's treedef and its leaves that are data
 
-    made = iter(jax.jit(
-        created, out_shardings=NamedSharding(xs.sharding.mesh, P(axis))
-    )(xs, ys))
-    return jax.tree.unflatten(treedef[0], [
+        def created(x, y):
+            leaves, layout["treedef"] = jax.tree.flatten(task.init_state(x, y))
+            layout["which"] = {i: j for i, leaf in enumerate(leaves)
+                               for j, arg in enumerate((x, y)) if leaf is arg}
+            return [leaf for i, leaf in enumerate(leaves)
+                    if i not in layout["which"]]
+
+        return jax.jit(created, out_shardings=NamedSharding(mesh, P(axis))), layout
+
+    (made_fn, layout), _ = engine.cached_program(
+        ("init_state", task, mesh, axis, engine.code_of(task)), build, telemetry
+    )
+    made = iter(made_fn(xs, ys))  # its first call traces and fills ``layout``
+    data, which, treedef = (xs, ys), layout["which"], layout["treedef"]
+    return jax.tree.unflatten(treedef, [
         data[which[i]] if i in which else next(made)
-        for i in range(treedef[0].num_leaves)
+        for i in range(treedef.num_leaves)
     ])
 
 
@@ -368,6 +376,20 @@ class KernelizedTask:
 
     def __getattr__(self, name):
         return getattr(self._base, name)
+
+    @property
+    def base(self):
+        return self._base
+
+    # Equal wrappers route alike, so the program cache keys on the value.
+    def _value(self):
+        return (self._base, self._kw["use_pallas"], self._kw["interpret"])
+
+    def __eq__(self, other):
+        return isinstance(other, KernelizedTask) and self._value() == other._value()
+
+    def __hash__(self):
+        return hash(self._value())
 
     # -- implicit-gradient operator, kernel-routed per state type ----------
     # (MTLSDenseState is not handled: the dense task lacks the epoch
@@ -664,14 +686,21 @@ def fit(
     whole by ``Telemetry.profiler``. Its top-level spans cover it:
     ``engine.setup`` (entry to the first segment dispatch; children
     ``engine.verify_kernels`` and ``engine.place``), then the engine's
-    ``engine.dispatch`` and ``engine.fetch``, then ``engine.final_loss``.
+    ``engine.dispatch``, ``engine.fetch`` and ``engine.history``, then
+    ``engine.final_loss``.
+
+    The job's jitted programs (the segment scans, the state init, the final
+    loss) come from the engine's process-wide program cache
+    (``engine.cached_program``): a later ``fit`` with equal settings, on data
+    of the same shapes and placement, runs them without tracing, lowering
+    or compiling again.
     """
     tel = cfg.telemetry if cfg.telemetry is not None else Telemetry.noop()
     # The body stays inline under this ``with``. A version that ran it in a
     # helper called from here lowered the segment's scan 70-90 ms slower
-    # per job on a v5e host (+7-9 % time to gap for the ImageNet head, with
-    # the telemetry off). A plain pass-through frame did not reproduce it;
-    # the cause is open (PERF.md, Findings and Open questions).
+    # on a v5e host (+7-9 % time to gap for the ImageNet head, with the
+    # telemetry off, when every job lowered its scan). A plain pass-through
+    # frame did not reproduce it; the cause is open (PERF.md, Findings).
     with tel.job(), tel.profiler(), contextlib.ExitStack() as setup:
         setup.enter_context(tel.span("engine.setup", "engine"))
         if mesh is None:
@@ -708,7 +737,7 @@ def fit(
         )
         reducer = topo.reducer
         comm_obj = reducer if tspec.kind == "flat" else topo
-        per_node = bool(getattr(comm_obj, "per_node", False))
+        per_node = bool(getattr(comm_obj, "per_node", False))  # REP002-ok: host attribute
 
         ktask = (
             kernelize(task, use_pallas=cfg.use_pallas, interpret=cfg.interpret)
@@ -729,7 +758,7 @@ def fit(
 
         with tel.span("engine.place", "engine"):
             xs, ys = shard_rowwise(mesh, (x, y), cfg.data_axis)
-            state = init_rowwise_state(ktask, xs, ys, cfg.data_axis)
+            state = init_rowwise_state(ktask, xs, ys, cfg.data_axis, tel)
             it = low_rank.init(max_rank, task.d, task.m)
 
             # Per-worker reducer state: every worker starts from the reducer's own
@@ -804,8 +833,8 @@ def fit(
             # else: keep the freshly initialized comm_state — an elastic remesh
             # (or a warm comm-mode change) re-derives per-worker state.
             same_sampling = (
-                float(snap.extra.get("sample_prob", -1.0)) == cfg.sample_prob
-                and bool(snap.extra.get("reweight", not cfg.reweight))
+                float(snap.extra.get("sample_prob", -1.0)) == cfg.sample_prob  # REP002-ok: host
+                and bool(snap.extra.get("reweight", not cfg.reweight))  # REP002-ok: host
                 == cfg.reweight
             )
             if (
@@ -823,13 +852,14 @@ def fit(
                 # Nothing left to run: the checkpoint already holds the final
                 # carry (epoch budget spent, or its gap certificate still
                 # stands under this config's gap_tol).
-                final_loss = float(jax.device_get(jax.jit(ktask.local_loss)(state)))
+                loss, reused = engine.final_loss(ktask, state, tel)
                 return DFWFitResult(
                     iterate=it, state=state, history=snap.history,
                     masks=masks[: snap.t] if sampling else None,
-                    final_loss=final_loss, epochs_run=snap.t,
+                    final_loss=loss, epochs_run=snap.t,
                     stats={"segments_planned": 0, "segments_run": 0,
-                           "dispatches": 1, "compilations": 1, "host_syncs": 1},
+                           "dispatches": 1, "compilations": 1,
+                           "programs_reused": int(reused), "host_syncs": 1},
                 )
 
         checkpointer = _make_checkpointer(
@@ -905,12 +935,11 @@ def fit(
         # The plain sum over the row-sharded state is already the global loss, and
         # straggler weights never apply here: this is the true full-data F.
         with tel.span("engine.final_loss", "engine"):
-            final_loss = float(
-                jax.device_get(jax.jit(ktask.local_loss)(eres.carry.state))
-            )
+            loss, reused = engine.final_loss(ktask, eres.carry.state, tel)
         eres.stats["dispatches"] += 1
         eres.stats["host_syncs"] += 1
         eres.stats["compilations"] += 1
+        eres.stats["programs_reused"] += reused
         it_out = eres.carry.iterate
         if per_node:
             # Report node 0's iterate — the same convention gossip checkpoints
@@ -922,7 +951,7 @@ def fit(
             state=eres.carry.state,
             history=eres.history,
             masks=masks[: eres.epochs_run] if sampling else None,
-            final_loss=final_loss,
+            final_loss=loss,
             epochs_run=eres.epochs_run,
             stats=eres.stats,
         )
@@ -1008,12 +1037,13 @@ def fit_serial(
         # per-worker residuals don't transfer to the one-worker run, and a
         # warm comm change starts its new encoding from scratch.
         if _resume_complete(snap, cfg):
-            final_loss = float(jax.device_get(jax.jit(ktask.local_loss)(state)))
+            loss, reused = engine.final_loss(ktask, state, cfg.telemetry)
             return DFWFitResult(
                 iterate=iterate, state=state, history=snap.history,
-                masks=None, final_loss=final_loss, epochs_run=snap.t,
+                masks=None, final_loss=loss, epochs_run=snap.t,
                 stats={"segments_planned": 0, "segments_run": 0,
-                       "dispatches": 1, "compilations": 1, "host_syncs": 1},
+                       "dispatches": 1, "compilations": 1,
+                       "programs_reused": int(reused), "host_syncs": 1},
             )
     checkpointer = _make_checkpointer(task, cfg, 1, reducer.spec, cfg.telemetry)
     if checkpointer is not None:

@@ -9,3 +9,17 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _empty_program_cache():
+    """Each test builds its own programs: the engine's process-wide program
+    cache would otherwise hand a test the executables an earlier test in
+    the same worker built, and the compile spans a test expects would not
+    appear."""
+    from repro.core import engine
+
+    engine.clear_programs()
+    yield

@@ -251,6 +251,7 @@ def test_serial_const2_pin_holds_with_telemetry_enabled():
                            key=jax.random.PRNGKey(1), step_size="linesearch")
     tel = Telemetry()
     contract = engine.dispatch_contract()
+    engine.clear_programs()  # both runs build their programs: equal stats
     with contract.guard():
         res = frank_wolfe.fit(task, task.init_state(x, y), mu=1.0,
                               num_epochs=30, key=jax.random.PRNGKey(1),
@@ -439,3 +440,163 @@ def test_compiled_epoch_names_the_solver_phases_in_its_hlo_metadata(solver):
     text = jax.jit(epoch).lower(carry).compile().as_text()
     for scope in ("dfw.loss", "dfw.power", "dfw.linesearch", "dfw.update"):
         assert f"/{scope}/" in text, scope
+
+
+# ---------------------------------------------------------------------------
+# Program reuse across runs (engine.cached_program)
+# ---------------------------------------------------------------------------
+
+# One change of each value a job's programs close over; None changes nothing.
+_REUSE_CHANGES = {
+    "same": None,
+    "mu": {"mu": 2.0},
+    "gap_tol": {"gap_tol": 1e-3},
+    "step_size": {"step_size": "default"},
+    "schedule_k": {"schedule": "const:3"},
+    "solver": {"solver": "block:2"},
+    "comm": {"comm": "int8"},
+    "topology": {"topology": "ring"},
+    "workers": {"workers": 4},
+    "kernelize": {"kernelize": False},
+    "use_pallas": {"use_pallas": False},
+    "task_d_m": {"d": 20, "m": 16},
+}
+
+
+def _reuse_job(tel, key, d=24, m=18, workers=1, **cfg_kw):
+    """One ``launch/dfw.fit`` job of a small MTLS problem; returns its result
+    and the change of ``tel``'s counters and the names of its spans."""
+    from repro.launch import dfw
+
+    x, y = _mtls(jax.random.PRNGKey(12), n=400, d=d, m=m)
+    task = tasks.MultiTaskLeastSquares(d=d, m=m)
+    cfg = dfw.DFWConfig(**{"mu": 1.0, "num_epochs": 6, "schedule": "const:2",
+                           "step_size": "linesearch", "telemetry": tel,
+                           **cfg_kw})
+    before = tel.registry.snapshot()["counters"]
+    t_us = tel.now_us()
+    res = dfw.fit(task, x, y, cfg=cfg, key=key, num_workers=workers)
+    after = tel.registry.snapshot()["counters"]
+    spans = {ev["name"] for ev in tel.events() if ev["ts"] >= t_us}
+    return res, {k: v - before.get(k, 0.0) for k, v in after.items()}, spans
+
+
+def _check_reuse(change):
+    """Two jobs with different keys, the second with ``change`` applied: an
+    equal configuration reuses every program and compiles nothing; a changed
+    closed-over value builds its scan anew. Either way the second job's
+    answer is bit-identical to the same job run on an empty cache."""
+    from repro.obs import Telemetry
+
+    tel = Telemetry(capture_hlo=False)
+    _reuse_job(tel, jax.random.PRNGKey(1))
+    kw = dict(change or {})
+    res, counts, spans = _reuse_job(tel, jax.random.PRNGKey(2), **kw)
+    if change is None:
+        assert res.stats["programs_reused"] >= 1, res.stats
+        assert res.stats["programs_reused"] == res.stats["compilations"]
+        assert counts["engine.program_cache.hits"] >= 3, counts
+        assert counts.get("engine.program_cache.misses", 0) == 0, counts
+        assert counts["jit.compiles"] == 0, counts
+        assert "engine.compile" not in spans
+    else:
+        assert counts["engine.program_cache.misses"] >= 1, counts
+        assert res.stats["programs_reused"] < res.stats["compilations"]
+        assert "engine.compile" in spans and counts["jit.compiles"] >= 1
+    engine.clear_programs()
+    fresh, _, fresh_spans = _reuse_job(Telemetry(capture_hlo=False),
+                                       jax.random.PRNGKey(2), **kw)
+    assert "engine.compile" in fresh_spans
+    assert fresh.stats["programs_reused"] == 0
+    assert res.history.keys() == fresh.history.keys()
+    for name in res.history:
+        np.testing.assert_array_equal(np.asarray(res.history[name]),
+                                      np.asarray(fresh.history[name]),
+                                      err_msg=name)
+    assert res.final_loss == fresh.final_loss
+    for a, b in zip(jax.tree.leaves(res.iterate), jax.tree.leaves(fresh.iterate)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("name", list(_REUSE_CHANGES))
+def test_a_second_job_reuses_programs_unless_a_closed_over_value_changed(name):
+    change = _REUSE_CHANGES[name]
+    if name != "workers":
+        _check_reuse(change)
+        return
+    # more than one worker needs more than the main process's one device
+    out = _run(f"""
+        import sys
+        sys.path.insert(0, {str(Path(__file__).resolve().parent)!r})
+        from test_engine import _check_reuse, _reuse_job
+        import test_engine
+        test_engine._reuse_job = lambda tel, key, **kw: _reuse_job(
+            tel, key, **{{"workers": 2, **kw}})
+        _check_reuse({change!r})
+        print("reuse across worker counts OK")
+    """)
+    assert "OK" in out
+
+
+def test_the_program_cache_holds_no_job_data():
+    """Programs are cached, arrays are not: once a job's result and data
+    are dropped, none of its arrays is alive, though its programs are."""
+    import gc
+
+    from repro.launch import dfw
+
+    n = 408  # a row count no other live array has
+    x, y = _mtls(jax.random.PRNGKey(13), n=n)
+    task = tasks.MultiTaskLeastSquares(d=24, m=18)
+    cfg = dfw.DFWConfig(mu=1.0, num_epochs=6, step_size="linesearch")
+    res = dfw.fit(task, x, y, cfg=cfg, key=jax.random.PRNGKey(1),
+                  num_workers=1)
+    assert any(a.shape[:1] == (n,) for a in jax.tree.leaves(res.state))
+    del res, x, y
+    gc.collect()
+    assert len(engine._programs) == 3  # scan, state init, final loss
+    assert not [a.shape for a in jax.live_arrays() if a.shape[:1] == (n,)]
+
+
+def test_the_program_cache_keeps_the_32_most_recently_used():
+    built = []
+
+    def lookup(key):
+        return engine.cached_program(key, lambda: built.append(key) or key)
+
+    for i in range(40):
+        assert lookup(("k", i)) == (("k", i), False)
+    assert len(engine._programs) == 32
+    assert lookup(("k", 8)) == (("k", 8), True)  # kept: now the newest
+    assert lookup(("k", 7)) == (("k", 7), False)  # dropped: built again
+    assert lookup(("k", 9)) == (("k", 9), False)  # oldest, dropped for k 7
+    assert lookup(("k", 8)) == (("k", 8), True)
+    assert len(engine._programs) == 32 and len(built) == 42
+
+
+def test_an_unhashable_key_is_built_every_time_and_counted_as_a_miss():
+    from repro.obs import Telemetry
+
+    tel = Telemetry()
+    for _ in range(2):
+        program, hit = engine.cached_program(["unhashable"], object, tel)
+        assert not hit and type(program) is object
+    assert not engine._programs
+    assert tel.registry.snapshot()["counters"]["engine.program_cache.misses"] == 2
+
+
+def test_a_method_patched_on_the_task_class_builds_the_programs_again(monkeypatch):
+    """The key holds the code of the task's class: a job after a patch runs
+    the patched method, as a job after a fresh start would."""
+    from repro.obs import Telemetry
+
+    tel = Telemetry(capture_hlo=False)
+    before, _, _ = _reuse_job(tel, jax.random.PRNGKey(1))
+    monkeypatch.setattr(tasks.MultiTaskLeastSquares, "update",
+                        lambda self, s, *a: s)
+    after, counts, spans = _reuse_job(tel, jax.random.PRNGKey(1))
+    assert counts["engine.program_cache.misses"] == 3, counts
+    assert "engine.compile" in spans
+    # the state never moves: every epoch sees the loss at W = 0
+    assert len(set(after.history["loss"])) == 1
+    assert len(set(before.history["loss"])) > 1

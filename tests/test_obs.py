@@ -325,8 +325,9 @@ def _dfw_job(tel, num_epochs=12):
     task = tasks.MultiTaskLeastSquares(d=24, m=18)
     cfg = dfw.DFWConfig(mu=1.0, num_epochs=num_epochs, schedule="const:2",
                         step_size="linesearch", telemetry=tel)
+    key = jax.random.PRNGKey(1)  # made before the clock starts: not the job's
     t0 = time.perf_counter()
-    res = dfw.fit(task, x, y, cfg=cfg, key=jax.random.PRNGKey(1), num_workers=1)
+    res = dfw.fit(task, x, y, cfg=cfg, key=key, num_workers=1)
     return res, time.perf_counter() - t0
 
 
@@ -348,11 +349,13 @@ def test_top_level_spans_of_a_fit_job_cover_its_wall_time():
     parents = {ev["name"]: ev["args"].get("parent") for ev in job}
     assert parents["engine.verify_kernels"] == "engine.setup"
     assert parents["engine.place"] == "engine.setup"
-    # each job retraces its fresh jax.jit inside the first dispatch
-    assert parents["engine.compile"] == "engine.dispatch"
-    assert {ev["args"]["parent"] for ev in job
-            if ev["name"] == "jit.trace"} <= {"engine.compile", "engine.dispatch",
-                                              "engine.place", "engine.final_loss"}
+    # the second job dispatches the programs the first one built: nothing
+    # is traced, lowered or compiled inside it
+    assert not {"engine.compile", "jit.trace", "jit.lower",
+                "jit.compile"} & set(parents)
+    counters = tel.registry.snapshot()["counters"]
+    assert counters["engine.program_cache.hits"] == 3  # scan, state, loss
+    assert counters["engine.program_cache.misses"] == 3  # all in the first job
 
 
 def test_complete_span_maps_onto_a_profiler_capture_by_the_offset_rule(tmp_path):
@@ -389,7 +392,8 @@ def test_a_second_identical_fit_reports_its_compiles_and_cache_hits(tmp_path):
         jax.config.update("jax_compilation_cache_dir", {str(tmp_path)!r})
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        from repro.core import tasks
+        import dataclasses
+        from repro.core import engine, tasks
         from repro.launch import dfw
         from repro.obs import Telemetry
 
@@ -402,24 +406,35 @@ def test_a_second_identical_fit_reports_its_compiles_and_cache_hits(tmp_path):
         cfg = dfw.DFWConfig(mu=1.0, num_epochs=10, schedule="const:2",
                             step_size="linesearch", telemetry=tel)
         jobs = []
-        for _ in range(2):
+        for mu, forget in ((1.0, False), (1.0, False), (2.0, False), (1.0, True)):
+            if forget:
+                engine.clear_programs()
             before = dict(tel.registry.snapshot()["counters"])
-            dfw.fit(task, X, Y, cfg=cfg, key=jax.random.PRNGKey(1), num_workers=2)
+            dfw.fit(task, X, Y, cfg=dataclasses.replace(cfg, mu=mu),
+                    key=jax.random.PRNGKey(1), num_workers=2)
             after = tel.registry.snapshot()["counters"]
             jobs.append({{k: v - before.get(k, 0.0) for k, v in after.items()
-                          if k.startswith("jit.")}})
-        first, second = jobs
+                          if k.startswith(("jit.", "engine.program_cache."))}})
+        first, second, third, fourth = jobs
         # a fresh cache: the first job compiles what it cannot load
         assert first["jit.cache_hits"] < first["jit.compiles"], first
-        # the fresh jax.jit objects of the second job retrace and load from
-        # the persistent cache: every executable is a hit, none is written
-        assert second["jit.compiles"] >= 2, second
-        assert second["jit.cache_hits"] == second["jit.compiles"], second
-        assert second["jit.trace_s"] > 0 and second["jit.compile_s"] > 0, second
+        # the second job reuses the first one's programs: nothing compiles
+        assert second["jit.compiles"] == 0, second
+        assert second["jit.trace_s"] == 0 and second["jit.lower_s"] == 0, second
+        assert second["engine.program_cache.hits"] == 3, second
+        # a changed mu is another scan: it alone is built and compiled anew
+        assert third["engine.program_cache.misses"] == 1, third
+        assert third["engine.program_cache.hits"] == 2, third
+        assert third["jit.compiles"] >= 1 and third["jit.trace_s"] > 0, third
+        # with the program cache emptied, the programs are traced again and
+        # every executable is loaded from the persistent cache, none written
+        assert fourth["jit.compiles"] >= 2, fourth
+        assert fourth["jit.cache_hits"] == fourth["jit.compiles"], fourth
+        assert fourth["jit.trace_s"] > 0 and fourth["jit.compile_s"] > 0, fourth
         names = {{e["args"]["fun_name"] for e in tel.events()
-                  if e["name"] == "jit.compile" and e["args"].get("job") == 2}}
+                  if e["name"] == "jit.compile" and e["args"].get("job") == 4}}
         assert "jit(local_loss)" in names, names
-        print("compile counters OK", second)
+        print("compile counters OK", second, third, fourth)
     """)
     assert "OK" in out
 
