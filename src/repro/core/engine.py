@@ -591,7 +591,7 @@ def run_epochs(
         )
     if sspec.kind == "block":
         if probe is None:
-            probe = init_probe(sspec, task.m)
+            probe = init_probe(sspec, task.m, key)
     else:
         probe = () if probe is None else probe
     if masks is not None:

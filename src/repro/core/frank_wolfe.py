@@ -123,21 +123,18 @@ def solver_probe_shape(spec, m: int) -> Optional[tuple]:
 
 
 def init_probe(spec, m: int, key: Optional[jax.Array] = None) -> PyTree:
-    """Cold-start probe for a fresh run: a deterministic orthonormal (m, k)
-    block for the block solver (built from ``key`` when given, else from a
-    fixed seed so every worker agrees without communication), ``()`` for
-    rank1."""
+    """Cold-start probe for a fresh run, ``()`` for rank1. For the block
+    solver an orthonormal (m, k) block drawn from epoch 0's key
+    ``fold_in(key, 0)``, as the rank-1 solver draws its epoch-0 vector, so
+    a run's key picks its start; the key is replicated, so every worker
+    agrees without communication. Without a key (shapes and tests), a fixed
+    seed."""
     shape = solver_probe_shape(spec, m)
     if shape is None:
         return ()
-    k = shape[1]
     if key is None:
         key = jax.random.PRNGKey(0x5EED)
-    cols = jnp.stack(
-        [sphere_vector(jax.random.fold_in(key, 101 + j), m) for j in range(k)],
-        axis=1,
-    )
-    return orthonormalize_block(cols)
+    return orthonormalize_block(jax.random.normal(jax.random.fold_in(key, 0), shape))
 
 
 # ---------------------------------------------------------------------------
@@ -487,7 +484,7 @@ def fit(
 
     ``solver`` selects the LMO tier (``parse_solver`` grammar). For the
     block tier, ``probe`` optionally resumes the warm-start block from a
-    checkpoint (``None`` cold-starts deterministically); an epoch appends
+    checkpoint (``None`` cold-starts from the run's key); an epoch appends
     k factors, so ``max_rank`` defaults to ``num_epochs * k``.
     """
     from .engine import final_loss, run_epochs  # engine builds on this module

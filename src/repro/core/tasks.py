@@ -23,15 +23,21 @@ import jax
 import jax.numpy as jnp
 
 
-def _uvt(xu: jax.Array, v: jax.Array) -> jax.Array:
+# Least squares pins its products to f32 (TPU's default precision rounds
+# f32 operands to bf16, and the residual R = XW - Y cancels what they carry).
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _uvt(xu: jax.Array, v: jax.Array, precision=None) -> jax.Array:
     """The atom's action on the sample axis: ``outer(xu, v)`` for a rank-1
     (vector) atom, ``xu @ v.T`` for a rank-k block whose u columns already
     carry the blend weights (the ``block:k`` solver's ``S = -mu sum_j c_j
-    u_j v_j^T`` with ``c`` folded into ``u``). ndim is static, so the
-    rank-1 path stays byte-identical to the pre-block code."""
+    u_j v_j^T`` with ``c`` folded into ``u``), at ``precision``. ndim is
+    static, so the rank-1 path stays byte-identical to the pre-block
+    code."""
     if xu.ndim == 1:
         return jnp.outer(xu, v)
-    return xu @ v.T
+    return jnp.matmul(xu, v.T, precision=precision)
 
 
 def _entrywise_uv(
@@ -74,16 +80,16 @@ class MultiTaskLeastSquares:
 
     # grad = X^T R ; never materialized.
     def matvec(self, s: MTLSState, v: jax.Array) -> jax.Array:
-        return s.x.T @ (s.r @ v)
+        return jnp.dot(s.x.T, jnp.dot(s.r, v, precision=_HI), precision=_HI)
 
     def rmatvec(self, s: MTLSState, u: jax.Array) -> jax.Array:
-        return s.r.T @ (s.x @ u)
+        return jnp.dot(s.r.T, jnp.dot(s.x, u, precision=_HI), precision=_HI)
 
     def update(self, s: MTLSState, u, v, gamma, mu) -> MTLSState:
         # R' = X[(1-g)W + g S] - Y = (1-g)R - g Y - g mu (X u) v^T
         # (block atoms: u (d,k) with blend weights folded in, v (m,k))
-        xu = s.x @ u
-        r = (1.0 - gamma) * s.r - gamma * s.y - (gamma * mu) * _uvt(xu, v)
+        xu = jnp.dot(s.x, u, precision=_HI)
+        r = (1.0 - gamma) * s.r - gamma * s.y - (gamma * mu) * _uvt(xu, v, _HI)
         return MTLSState(x=s.x, y=s.y, r=r)
 
     def local_loss(self, s: MTLSState) -> jax.Array:
@@ -94,7 +100,7 @@ class MultiTaskLeastSquares:
         return jnp.sum((s.r + s.y) * s.r)
 
     def local_grad(self, s: MTLSState) -> jax.Array:
-        return s.x.T @ s.r
+        return jnp.dot(s.x.T, s.r, precision=_HI)
 
     def linesearch_terms(self, s: MTLSState, u, v, mu):
         """Local (numerator, denominator) of the closed-form step (App. B):
@@ -103,7 +109,7 @@ class MultiTaskLeastSquares:
         computed via X D = -mu (X u) v^T - (R + Y)  — all O(n_j(d+m)).
         Returns local contributions; caller psums then divides.
         """
-        xd = -(mu) * _uvt(s.x @ u, v) - (s.r + s.y)
+        xd = -(mu) * _uvt(jnp.dot(s.x, u, precision=_HI), v, _HI) - (s.r + s.y)
         numer = -jnp.sum(s.r * xd)
         denom = jnp.sum(xd * xd)
         return numer, denom

@@ -777,8 +777,8 @@ def fit(
             )
 
             # Block-solver warm-start probe: replicated (m, k) block, cold-started
-            # deterministically (() for rank1 — zero extra carry leaves).
-            probe_blk = frank_wolfe.init_probe(sspec, task.m)
+            # from the run's key (() for rank1 — zero extra carry leaves).
+            probe_blk = frank_wolfe.init_probe(sspec, task.m, key)
             if sspec.kind == "block":
                 probe_blk = jax.device_put(probe_blk, NamedSharding(mesh, P()))
 

@@ -119,6 +119,20 @@ def test_init_probe_shapes():
     np.testing.assert_allclose(np.asarray(p.T @ p), np.eye(3), atol=1e-5)
 
 
+def test_init_probe_is_drawn_from_the_runs_key():
+    """A block run starts from epoch 0's key, as a rank-1 run does: block:1's
+    probe is the rank-1 solver's epoch-0 vector (up to sign), and two keys
+    give two starts."""
+    key = jax.random.PRNGKey(7)
+    p1 = np.asarray(frank_wolfe.init_probe("block:1", 30, key))[:, 0]
+    v0 = np.asarray(power_method.sphere_vector(jax.random.fold_in(key, 0), 30))
+    assert abs(float(p1 @ v0)) == pytest.approx(1.0, abs=1e-5)
+    a = np.asarray(frank_wolfe.init_probe("block:3", 30, key))
+    b = np.asarray(frank_wolfe.init_probe("block:3", 30, jax.random.PRNGKey(8)))
+    np.testing.assert_allclose(a.T @ a, np.eye(3), atol=1e-5)
+    assert not np.allclose(np.abs(a), np.abs(b))
+
+
 # ---------------------------------------------------------------------------
 # Block power primitives
 # ---------------------------------------------------------------------------
@@ -135,6 +149,23 @@ def test_orthonormalize_block():
     # all-zero block maps to all-zero block (jitter keeps cholesky defined)
     z = power_method.orthonormalize_block(jnp.zeros((50, 6)))
     assert np.all(np.isfinite(np.asarray(z)))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_orthonormalize_rank_deficient_wide_block_stays_finite(seed):
+    """A width-32 block of rank 10 at d = 1000, as the least-squares
+    gradient against a rank-10 truth gives: its f32 Gram's null
+    eigenvalues round below zero, and the jitter has to cover them or the
+    Cholesky factor is NaN. The first 10 columns span the block, and no
+    column comes out longer than a unit vector."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    a = jax.random.normal(k1, (1000, 10)) * jnp.linspace(1.0, 0.1, 10)
+    b = np.asarray(a @ jax.random.normal(k2, (10, 32)))
+    q = np.asarray(power_method.orthonormalize_block(jnp.asarray(b)))
+    assert np.all(np.isfinite(q))
+    p = np.linalg.qr(q[:, :10])[0]
+    assert np.linalg.norm(b - p @ (p.T @ b)) < 1e-3 * np.linalg.norm(b)
+    assert np.all(np.linalg.norm(q, axis=0) <= 1.0 + 1e-3)
 
 
 def test_block_power_recovers_top_k():

@@ -7,6 +7,7 @@ subprocesses with 8 fake CPU devices (the device count locks at the first
 jax init in the main pytest process), matching tests/test_dfw_launch.py.
 """
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -440,6 +441,29 @@ def test_compiled_epoch_names_the_solver_phases_in_its_hlo_metadata(solver):
     text = jax.jit(epoch).lower(carry).compile().as_text()
     for scope in ("dfw.loss", "dfw.power", "dfw.linesearch", "dfw.update"):
         assert f"/{scope}/" in text, scope
+
+
+def test_block_epoch_names_its_orthonormalizations_inside_the_power_scope():
+    """The block LMO's Cholesky-QR steps (Gram, Cholesky, triangular solve)
+    carry ``dfw.orth`` inside ``dfw.power``, so the device trace splits the
+    k-wide algebra from the matvec kernels; the rank-1 epoch has none."""
+    from repro.core import low_rank
+
+    x, y = _mtls(jax.random.PRNGKey(11))
+    task = tasks.MultiTaskLeastSquares(d=24, m=18)
+    texts = {}
+    for solver in ("rank1", "block:2"):
+        carry = frank_wolfe.init_carry(
+            task.init_state(x, y), low_rank.init(8, 24, 18), jax.random.PRNGKey(1),
+            (), probe=frank_wolfe.init_probe(frank_wolfe.parse_solver(solver), 18))
+        epoch = frank_wolfe.make_epoch_step(task, 1.0, 2, step_size="linesearch",
+                                            reducer=comm.DenseReducer(),
+                                            solver=solver)
+        texts[solver] = jax.jit(epoch).lower(carry).compile().as_text()
+    for op in ("dot_general", "cholesky", "triangular_solve"):
+        assert re.search(rf'op_name="[^"]*/dfw\.power/dfw\.orth/[^"]*{op}',
+                         texts["block:2"]), op
+    assert "dfw.orth" not in texts["rank1"]
 
 
 # ---------------------------------------------------------------------------
