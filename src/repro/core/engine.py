@@ -57,6 +57,7 @@ from . import low_rank
 from .frank_wolfe import (
     EpochAux,
     EpochCarry,
+    has_block_products,
     init_carry,
     init_probe,
     k_schedule,
@@ -665,6 +666,11 @@ def run_epochs(
         compiled[sig] = program
         stats["compilations"] += 1
         stats["programs_reused"] += hit
+        if sspec.kind == "block" and tel.enabled:
+            # How the program's block LMO forms its products (built or
+            # reused alike), so a job's counters show the route it ran.
+            route = "kwide" if has_block_products(task) else "vmap"
+            tel.registry.counter(f"power.block_programs.{route}").inc()
         return program, not hit
 
     def _emit_executable_cost(seg: Segment, exe) -> None:

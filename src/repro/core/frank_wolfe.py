@@ -137,6 +137,14 @@ def init_probe(spec, m: int, key: Optional[jax.Array] = None) -> PyTree:
     return orthonormalize_block(jax.random.normal(jax.random.fold_in(key, 0), shape))
 
 
+def has_block_products(task) -> bool:
+    """Whether the block LMO can take ``task``'s k-wide ``matmat`` /
+    ``rmatmat`` (one pass over the local data for all k probe columns)
+    instead of a ``vmap`` of its vector products. A kernelized task has
+    them where the task it wraps does."""
+    return hasattr(getattr(task, "base", task), "matmat")
+
+
 # ---------------------------------------------------------------------------
 # K(t) schedules (paper Thm 2 + experimental settings §5)
 # ---------------------------------------------------------------------------
@@ -279,6 +287,11 @@ def make_epoch_step(
                     # falls back to its random column.
                     col_norm = jnp.linalg.norm(carry.probe, axis=0, keepdims=True)
                     v0 = jnp.where(col_norm > 1e-6, carry.probe, rand0)
+                if has_block_products(task):
+                    block_kw = dict(matmat=partial(task.matmat, state),
+                                    rmatmat=partial(task.rmatmat, state))
+                else:
+                    block_kw = {}
                 res, comm_state = block_power_iterations(
                     partial(task.matvec, state),
                     partial(task.rmatvec, state),
@@ -294,6 +307,7 @@ def make_epoch_step(
                     # certificate is inner + mu*sigma_max, so changes small
                     # relative to |inner|/mu (or sigma itself) can't move it.
                     adapt_ref=jnp.abs(inner) / mu,
+                    **block_kw,
                 )
                 sigma_max = jnp.max(res.sigma)
                 gap = duality_gap(inner, sigma_max, mu)

@@ -288,6 +288,8 @@ def block_power_iterations(
     key: Optional[jax.Array] = None,
     adapt_rtol: Optional[float] = None,
     adapt_ref: Optional[jax.Array] = None,
+    matmat: Optional[Callable[[jax.Array], jax.Array]] = None,
+    rmatmat: Optional[Callable[[jax.Array], jax.Array]] = None,
 ):
     """Distributed *block* power iteration: ``(d,k)``/``(m,k)`` probe blocks
     instead of vectors — the rank-k LMO engine of the ``block:k`` solver
@@ -312,6 +314,11 @@ def block_power_iterations(
     certificate lives (the FW epoch uses ``|<W, grad>| / mu``), so
     iterations are spent only while they still move the certificate.
 
+    ``matmat``/``rmatmat`` are the local block products ``A_j V`` /
+    ``A_j^T U`` where the caller has them as one product (one pass over the
+    data for all k columns); without them the block products are ``vmap``s
+    of ``matvec``/``rmatvec``, k vector products.
+
     Always returns ``(BlockPowerResult, comm_state)`` (``reducer=None``
     uses the exact dense psum with ``()`` state).
     """
@@ -330,8 +337,10 @@ def block_power_iterations(
         key = jax.random.PRNGKey(0)
     m, k = v0.shape
     w = 1.0 if worker_weight is None else worker_weight
-    matmat = jax.vmap(matvec, in_axes=1, out_axes=1)
-    rmatmat = jax.vmap(rmatvec, in_axes=1, out_axes=1)
+    if matmat is None:
+        matmat = jax.vmap(matvec, in_axes=1, out_axes=1)
+    if rmatmat is None:
+        rmatmat = jax.vmap(rmatvec, in_axes=1, out_axes=1)
     d = matmat(v0).shape[0]  # shapes only; dead under jit (loop recomputes)
     if comm_state is None:
         comm_state = reducer.init_state(d * k, m * k)

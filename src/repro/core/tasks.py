@@ -8,6 +8,8 @@ Interface (duck-typed; see ``frank_wolfe.DFWTask``):
     init_state(X, Y)      -> state pytree (local shard)
     matvec(state, v)      -> local  grad_j @ v          (d,)
     rmatvec(state, u)     -> local  grad_j^T @ u        (m,)
+    matmat(state, V)      -> local  grad_j @ V          (d,k)  (optional:
+    rmatmat(state, U)     -> local  grad_j^T @ U        (m,k)   one product)
     update(state,u,v,g,mu)-> state after W <- (1-g)W - g*mu u v^T
     local_loss(state)     -> local loss contribution    ()
     inner_w_grad(state)   -> local <W, grad_j>          ()   (duality gap)
@@ -84,6 +86,13 @@ class MultiTaskLeastSquares:
 
     def rmatvec(self, s: MTLSState, u: jax.Array) -> jax.Array:
         return jnp.dot(s.r.T, jnp.dot(s.x, u, precision=_HI), precision=_HI)
+
+    # The same chains take a whole (m, k) / (d, k) probe block at once.
+    def matmat(self, s: MTLSState, v: jax.Array) -> jax.Array:
+        return self.matvec(s, v)
+
+    def rmatmat(self, s: MTLSState, u: jax.Array) -> jax.Array:
+        return self.rmatvec(s, u)
 
     def update(self, s: MTLSState, u, v, gamma, mu) -> MTLSState:
         # R' = X[(1-g)W + g S] - Y = (1-g)R - g Y - g mu (X u) v^T

@@ -23,24 +23,53 @@ def _pad_to(x: jax.Array, mult_r: int, mult_c: int) -> jax.Array:
     return jnp.pad(x, ((0, pr), (0, pc)))
 
 
+# Tiles of a k-wide pass (k > 1): its products are (k, len), so row and
+# column tiles both lie along lanes and are multiples of 128; at most
+# 512 x 1024 f32 (2 MiB a buffer), the fastest of a v5e sweep at
+# 100,000 x 1000.
+_WIDE_ROWS, _WIDE_COLS, _LANE = 512, 1024, 128
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def _tiles(n: int, m: int, k: int, block_r, block_c) -> tuple:
+    """``(block_r, block_c)`` for an (n, m) matrix and k probe columns:
+    the caller's, else 256 x 256 for a vector and, for a block, row tiles of
+    512 and the columns split evenly into the fewest tiles of <= 1024."""
+    if k == 1:
+        return block_r or 256, block_c or 256
+    if block_r is None:
+        block_r = min(_WIDE_ROWS, _round_up(n, _LANE))
+    if block_c is None:
+        col_tiles = -(-m // _WIDE_COLS)
+        block_c = _round_up(-(-m // col_tiles), _LANE)
+    return block_r, block_c
+
+
 @functools.partial(jax.jit, static_argnames=("block_r", "block_c", "use_pallas", "interpret"))
 def matvec(
     a: jax.Array,
     v: jax.Array,
     *,
-    block_r: int = 256,
-    block_c: int = 256,
+    block_r: int | None = None,
+    block_c: int | None = None,
     use_pallas: bool | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """A @ v -> (n,). Zero-pads to block multiples (zeros are exact no-ops)."""
+    """A @ v: v (m,) -> (n,), or a block v (m, k) -> (n, k) in one pass over
+    A. Zero-pads to block multiples (zeros are exact no-ops)."""
     n, m = a.shape
+    vb = v.reshape(m, -1)
     if not _use_pallas(use_pallas) and not interpret:
-        return ref.matvec(a, v)[:, 0]
+        out = ref.matvec(a, vb)
+        return out if v.ndim == 2 else out[:, 0]
+    block_r, block_c = _tiles(n, m, vb.shape[1], block_r, block_c)
     ap = _pad_to(a, block_r, block_c)
-    vp = _pad_to(v.reshape(m, 1), block_c, 1)
+    vp = _pad_to(vb, block_c, 1)
     out = kernel.matvec(ap, vp, block_r=block_r, block_c=block_c, interpret=interpret)
-    return out[:n, 0]
+    return out[:n] if v.ndim == 2 else out[:n, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "block_c", "use_pallas", "interpret"))
@@ -48,19 +77,22 @@ def rmatvec(
     a: jax.Array,
     u: jax.Array,
     *,
-    block_r: int = 256,
-    block_c: int = 256,
+    block_r: int | None = None,
+    block_c: int | None = None,
     use_pallas: bool | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """A^T @ u -> (m,)."""
+    """A^T @ u: u (n,) -> (m,), or a block u (n, k) -> (m, k)."""
     n, m = a.shape
+    ub = u.reshape(n, -1)
     if not _use_pallas(use_pallas) and not interpret:
-        return ref.rmatvec(a, u)[:, 0]
+        out = ref.rmatvec(a, ub)
+        return out if u.ndim == 2 else out[:, 0]
+    block_r, block_c = _tiles(n, m, ub.shape[1], block_r, block_c)
     ap = _pad_to(a, block_r, block_c)
-    up = _pad_to(u.reshape(n, 1), block_r, 1)
+    up = _pad_to(ub, block_r, 1)
     out = kernel.rmatvec(ap, up, block_r=block_r, block_c=block_c, interpret=interpret)
-    return out[:m, 0]
+    return out[:m] if u.ndim == 2 else out[:m, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))

@@ -361,7 +361,8 @@ class KernelizedTask:
     On TPU each call is a single-HBM-pass blocked Pallas kernel; elsewhere the
     ops dispatch to the pure-jnp reference (``power_matvec/ref.py``), so the
     wrapper is a no-op semantically everywhere. Everything except
-    matvec/rmatvec is delegated to the base task untouched.
+    matvec/rmatvec and matmat/rmatmat is delegated to the base task
+    untouched.
     """
 
     def __init__(
@@ -419,6 +420,19 @@ class KernelizedTask:
             return mc_ops.rmatvec(s.rows, s.cols, s.resid, u, self._base.m, **self._kw)
         return self._base.rmatvec(s, u)
 
+    # k-wide products, present where the base task has them
+    # (``frank_wolfe.has_block_products``): the same kernel chains with a
+    # (., k) block, so each product is one pass over X and R.
+    def matmat(self, s, v: jax.Array) -> jax.Array:
+        if isinstance(s, tasks.MTLSState):
+            return self.matvec(s, v)
+        return self._base.matmat(s, v)
+
+    def rmatmat(self, s, u: jax.Array) -> jax.Array:
+        if isinstance(s, tasks.MTLSState):
+            return self.rmatvec(s, u)
+        return self._base.rmatmat(s, u)
+
 
 def kernelize(task, *, use_pallas: Optional[bool] = None, interpret: bool = False):
     """Wrap ``task`` so its power-iteration matvecs run through the Pallas ops."""
@@ -434,19 +448,33 @@ def verify_kernelized(
     key: jax.Array,
     *,
     tol: float = 1e-4,
+    k: int = 1,
 ) -> float:
     """Assert kernel-routed matvec/rmatvec match the base task's pure-jnp
     operator chain (the same oracle ``kernels/power_matvec/ref.py`` encodes)
     on random unit probes. Returns the max relative error observed.
 
+    For a block solver of width ``k`` > 1 on a task with k-wide products
+    (``frank_wolfe.has_block_products``) it probes the route the block LMO
+    takes instead: ``matmat``/``rmatmat`` on one (m, k) and one (d, k)
+    block of unit columns.
+
     The oracle runs at ``Precision.HIGHEST``, so on TPU (whose default f32
     matmul rounds operands to bf16) the check compares the kernels with the
     f32 maths, not with another precision mode."""
     kv, ku = jax.random.split(key)
-    v = sphere_vector(kv, task.m)
-    u = sphere_vector(ku, task.d)
+    if k > 1 and frank_wolfe.has_block_products(task):
+        v = jax.random.normal(kv, (task.m, k))
+        u = jax.random.normal(ku, (task.d, k))
+        v, u = v / jnp.linalg.norm(v, axis=0), u / jnp.linalg.norm(u, axis=0)
+        fwd, bwd = "matmat", "rmatmat"
+    else:
+        v = sphere_vector(kv, task.m)
+        u = sphere_vector(ku, task.d)
+        fwd, bwd = "matvec", "rmatvec"
     with jax.default_matmul_precision("highest"):
-        want_v, want_u = task.matvec(state, v), task.rmatvec(state, u)
+        want_v = getattr(task, fwd)(state, v)
+        want_u = getattr(task, bwd)(state, u)
 
     def rel_err(a, b):
         a, b = jnp.asarray(a), jnp.asarray(b)
@@ -457,8 +485,8 @@ def verify_kernelized(
         ))
 
     err = max(
-        rel_err(ktask.matvec(state, v), want_v),
-        rel_err(ktask.rmatvec(state, u), want_u),
+        rel_err(getattr(ktask, fwd)(state, v), want_v),
+        rel_err(getattr(ktask, bwd)(state, u), want_u),
     )
     if err > tol:
         raise AssertionError(
@@ -749,7 +777,8 @@ def fit(
                 # Probe on a small host-local slice before committing to the run.
                 probe_rows = min(x.shape[0], max(nw, 64))
                 probe = task.init_state(x[:probe_rows], y[:probe_rows])
-                verify_kernelized(task, ktask, probe, jax.random.fold_in(key, 0x5EED))
+                verify_kernelized(task, ktask, probe, jax.random.fold_in(key, 0x5EED),
+                                  k=k_block)
             if isinstance(reducer, comm_lib.Int8Reducer) and cfg.verify_kernels:
                 comm_lib.verify_quantize_kernels(
                     jax.random.fold_in(key, 0x17F8),

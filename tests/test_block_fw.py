@@ -15,6 +15,7 @@ import os
 import subprocess
 import sys
 import textwrap
+from functools import partial
 from pathlib import Path
 
 import jax
@@ -383,6 +384,119 @@ def test_block_telemetry_through_registry():
     snap = tel.registry.snapshot()
     assert snap["gauges"]["dfw.block.k"] == 3
     assert snap["counters"]["dfw.block.power_iters"] == 6 * 2  # const:2
+
+
+# ---------------------------------------------------------------------------
+# k-wide block products: one pass over the data for all k probe columns
+# ---------------------------------------------------------------------------
+
+
+def _lsq_cut(key, n=8192, d=500, m=500):
+    """The least-squares cell's CPU cut (d = m = 500, 8,192 rows) with
+    noisy targets, so the gradient at W = 0 has full rank and every probe
+    column is pinned by the spectrum, not by rounding."""
+    x, y = _mtls(key, n=n, d=d, m=m, rank=10)
+    y = y + 1e-3 * jax.random.normal(jax.random.fold_in(key, 7), y.shape)
+    return tasks.MultiTaskLeastSquares(d=d, m=m), x, y
+
+
+def test_block_power_kwide_route_matches_vmap_route():
+    task, x, y = _lsq_cut(jax.random.PRNGKey(20))
+    state = task.init_state(x, y)
+    v0 = frank_wolfe.init_probe("block:32", task.m, jax.random.PRNGKey(3))
+
+    @partial(jax.jit, static_argnums=0)
+    def lmo(native):
+        kw = (dict(matmat=lambda v: task.matmat(state, v),
+                   rmatmat=lambda u: task.rmatmat(state, u)) if native else {})
+        res, _ = power_method.block_power_iterations(
+            lambda v: task.matvec(state, v), lambda u: task.rmatvec(state, u),
+            v0, 2, **kw)
+        return res.sigma, res.u, res.v
+
+    want, got = lmo(False), lmo(True)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=1e-5)
+    np.testing.assert_allclose(got[2], want[2], atol=1e-5)
+
+
+def test_kernelized_matmat_matches_base_task_at_highest():
+    task, x, y = _lsq_cut(jax.random.PRNGKey(21), n=300, d=40, m=28)
+    state = task.init_state(x, y)
+    ktask = dfw.kernelize(task, interpret=True)
+    v = jax.random.normal(jax.random.PRNGKey(4), (task.m, 5))
+    u = jax.random.normal(jax.random.PRNGKey(5), (task.d, 5))
+    with jax.default_matmul_precision("highest"):
+        want_v, want_u = task.matmat(state, v), task.rmatmat(state, u)
+    for got, want in ((ktask.matmat(state, v), want_v),
+                      (ktask.rmatmat(state, u), want_u)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(want))))
+
+
+def _completion_obs(key, d=30, m=24):
+    """A 40 % observed (d, m) completion problem, packed."""
+    rows, cols = jnp.nonzero(jax.random.bernoulli(key, 0.4, (d, m)))
+    vals = jax.random.normal(jax.random.fold_in(key, 1), rows.shape) / 10
+    return tasks.pack_observations(rows, cols, vals)
+
+
+_PROBE_NDIMS: list = []
+
+
+@dataclasses.dataclass(frozen=True)
+class _SpyCompletion(tasks.MatrixCompletion):
+    """Matrix completion that records the rank of each probe its vector
+    products are traced with."""
+
+    def matvec(self, s, v):
+        _PROBE_NDIMS.append(v.ndim)
+        return super().matvec(s, v)
+
+
+def test_completion_block_lmo_takes_the_vmap_fallback():
+    task = _SpyCompletion(d=30, m=24)
+    assert not frank_wolfe.has_block_products(task)
+    assert not frank_wolfe.has_block_products(dfw.kernelize(task))
+    assert not frank_wolfe.has_block_products(tasks.MultinomialLogistic(d=8, m=4))
+    assert frank_wolfe.has_block_products(dfw.kernelize(
+        tasks.MultiTaskLeastSquares(d=8, m=4)))
+    _PROBE_NDIMS.clear()
+    res = frank_wolfe.fit(
+        task, task.init_state(*_completion_obs(jax.random.PRNGKey(6))),
+        mu=1.0, num_epochs=3, key=jax.random.PRNGKey(1), solver="block:2")
+    assert np.isfinite(res.history["loss"]).all()
+    # vmap hands the vector product one column at a time
+    assert _PROBE_NDIMS and set(_PROBE_NDIMS) == {1}
+
+
+@pytest.mark.parametrize("kind", ["lsq", "completion"])
+def test_block_program_counters_count_the_route(kind):
+    from repro.obs import Telemetry
+
+    if kind == "lsq":
+        x, y = _mtls(jax.random.PRNGKey(11), n=64)
+        task = tasks.MultiTaskLeastSquares(d=24, m=18)
+        state, route = task.init_state(x, y), "kwide"
+    else:
+        task = tasks.MatrixCompletion(d=30, m=24)
+        state = task.init_state(*_completion_obs(jax.random.PRNGKey(6)))
+        route = "vmap"
+    tel = Telemetry()
+    for _ in range(2):  # the second fit reuses the cached program
+        frank_wolfe.fit(task, state, mu=1.0, num_epochs=4,
+                        key=jax.random.PRNGKey(1), solver="block:3",
+                        telemetry=tel)
+    counters = tel.registry.snapshot()["counters"]
+    assert counters[f"power.block_programs.{route}"] == 2
+    other = "vmap" if route == "kwide" else "kwide"
+    assert f"power.block_programs.{other}" not in counters
+    # a rank-1 run builds no block program
+    frank_wolfe.fit(task, state, mu=1.0, num_epochs=2,
+                    key=jax.random.PRNGKey(1), telemetry=tel)
+    assert tel.registry.snapshot()["counters"][
+        f"power.block_programs.{route}"] == 2
 
 
 # ---------------------------------------------------------------------------

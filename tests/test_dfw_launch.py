@@ -299,6 +299,25 @@ def test_verify_kernelized_catches_divergence():
         dfw.verify_kernelized(task, Broken(task), s, key)
 
 
+def test_verify_kernelized_probes_the_block_route():
+    """For a block solver on a task with k-wide products the check runs
+    matmat/rmatmat: a fault there alone passes the vector probes and trips
+    the block ones."""
+    task = tasks.MultiTaskLeastSquares(d=8, m=6)
+    key = jax.random.PRNGKey(8)
+    s = task.init_state(jax.random.normal(key, (32, 8)),
+                        jax.random.normal(jax.random.fold_in(key, 1), (32, 6)))
+
+    class BrokenBlock(dfw.KernelizedTask):
+        def rmatmat(self, st, u):
+            return 2.0 * super().rmatmat(st, u)
+
+    assert dfw.verify_kernelized(task, dfw.kernelize(task), s, key, k=4) < 1e-4
+    assert dfw.verify_kernelized(task, BrokenBlock(task), s, key) < 1e-4
+    with pytest.raises(AssertionError, match="diverges"):
+        dfw.verify_kernelized(task, BrokenBlock(task), s, key, k=4)
+
+
 def _bf16(a):
     return a.astype(jnp.bfloat16).astype(a.dtype)
 

@@ -36,6 +36,29 @@ def test_matvec_rmatvec(n, m, dt):
         np.asarray(power_matvec.ref.rmatvec(a, u)[:, 0], np.float32), **_tol(dt))
 
 
+@pytest.mark.parametrize("k", [1, 3, 32])
+@pytest.mark.parametrize("n,m", [(300, 200), (65, 33)])
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
+def test_matvec_rmatvec_block(n, m, dt, k):
+    """A (., k) probe block in one pass: each column is the vector kernel's
+    product, and a 1-D probe still gives a 1-D result."""
+    a = (jax.random.normal(KEY, (n, m)) / np.sqrt(m)).astype(dt)
+    v = jax.random.normal(jax.random.fold_in(KEY, 1), (m, k)).astype(dt)
+    u = jax.random.normal(jax.random.fold_in(KEY, 2), (n, k)).astype(dt)
+    got = power_matvec.matvec(a, v, interpret=True)
+    assert got.shape == (n, k)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(power_matvec.ref.matvec(a, v), np.float32), **_tol(dt))
+    got = power_matvec.rmatvec(a, u, interpret=True)
+    assert got.shape == (m, k)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(power_matvec.ref.rmatvec(a, u), np.float32), **_tol(dt))
+    assert power_matvec.matvec(a, v[:, 0], interpret=True).shape == (n,)
+    assert power_matvec.rmatvec(a, u[:, 0], interpret=True).shape == (m,)
+
+
 def test_power_iter_step_matches_ref():
     n, d, m = 300, 40, 28
     x = jax.random.normal(KEY, (n, d)) / np.sqrt(d)

@@ -68,6 +68,20 @@ def test_power_matvec_compiles_at_head_width(one_chip, op, cols):
 
 
 @pytest.mark.parametrize("op", ["matvec", "rmatvec"])
+def test_power_matvec_compiles_k_wide_at_fig1_width(one_chip, op):
+    """The block:32 LMO's k-wide products over the Fig. 1 least-squares
+    data (100,000 x 1000): one kernel call, no batch grid over columns."""
+    a = _sds(one_chip, (100_000, 1000))
+    if op == "matvec":
+        fn = lambda a, v: pm_ops.matvec(a, v, use_pallas=True)  # noqa: E731
+        blk = _sds(one_chip, (1000, 32))
+    else:
+        fn = lambda a, u: pm_ops.rmatvec(a, u, use_pallas=True)  # noqa: E731
+        blk = _sds(one_chip, (100_000, 32))
+    assert tpu_kernel_calls(_compile(fn, a, blk)) == 1
+
+
+@pytest.mark.parametrize("op", ["matvec", "rmatvec"])
 def test_mc_matvec_compiles_at_completion_width(one_chip, op):
     d = m = 1024
     p = d * m // 10  # 10% of entries observed
