@@ -29,9 +29,9 @@ ROOT = Path(__file__).resolve().parent
 OUT = ROOT / ".chip_smoke"
 
 # Correctness limits, each on the figure its phase prints.
-HEAD_RTOL = 1e-3  # loss history vs the kernelize=False run
-MTLS_INT8_RTOL = 2e-2  # int8 exchange vs the dense, kernel-free run
-COMPLETION_RTOL = 1e-3  # loss history vs the kernelize=False run
+HEAD_RTOL = 1e-3  # loss history vs the f32 run (the task's kernels="ref")
+MTLS_INT8_RTOL = 2e-2  # int8 exchange vs the dense f32 run (kernels="ref")
+COMPLETION_RTOL = 1e-3  # loss history vs the f32 run (kernels="ref")
 SERVE_RTOL = 1e-4  # scores vs the dense product at Precision.HIGHEST
 MULTI_DENSE_RTOL = 1e-3  # 4-chip flat/dense final loss vs fit_serial
 MULTI_INT8_RTOL = 2e-2  # 4-chip hier:2/int8 final loss vs fit_serial
@@ -176,8 +176,8 @@ def phase_head(jax, seed: int, n: int = 327_680, p: int = 2048,
     cfg = dfw.DFWConfig(mu=mu, num_epochs=epochs, schedule="const:2")
     key = jax.random.PRNGKey(seed + 1)
     res, t = timed_fit(dfw.fit, task, x, y, cfg=cfg, key=key, num_workers=1)
-    ref, _ = timed_fit(dfw.fit, task, x, y, key=key, num_workers=1,
-                       cfg=dataclasses.replace(cfg, kernelize=False))
+    ref, _ = timed_fit(dfw.fit, dataclasses.replace(task, kernels="ref"), x, y,
+                       cfg=cfg, key=key, num_workers=1)
     dev = history_dev(res.history["loss"], ref.history["loss"])
     loss = res.history["loss"]
     report("head", n=n, p=p, m=m, epochs=epochs, compile_s=t["compile_s"],
@@ -207,9 +207,9 @@ def phase_mtls(jax, seed: int, n: int = 65_536, d: int = 1024,
     key = jax.random.PRNGKey(seed + 3)
     res, t = timed_fit(dfw.fit, task, x, y, cfg=cfg, key=key, num_workers=1)
     ref, _ = timed_fit(
-        dfw.fit, task, x, y, key=key, num_workers=1,
-        cfg=dataclasses.replace(cfg, kernelize=False, comm="dense",
-                                checkpoint_dir=None),
+        dfw.fit, dataclasses.replace(task, kernels="ref"), x, y, key=key,
+        num_workers=1,
+        cfg=dataclasses.replace(cfg, comm="dense", checkpoint_dir=None),
     )
     dev = history_dev(res.history["loss"], ref.history["loss"])
     loss = res.history["loss"]
@@ -239,8 +239,8 @@ def phase_completion(jax, seed: int, d: int = 1024, m: int = 1024) -> None:
                         step_size="linesearch")
     key = jax.random.PRNGKey(seed + 5)
     res, t = timed_fit(dfw.fit, task, idx, yw, cfg=cfg, key=key, num_workers=1)
-    ref, _ = timed_fit(dfw.fit, task, idx, yw, key=key, num_workers=1,
-                       cfg=dataclasses.replace(cfg, kernelize=False))
+    ref, _ = timed_fit(dfw.fit, dataclasses.replace(task, kernels="ref"), idx,
+                       yw, cfg=cfg, key=key, num_workers=1)
     dev = history_dev(res.history["loss"], ref.history["loss"])
     loss = res.history["loss"]
     report("completion", d=d, m=m, observed=len(vals), epochs=cfg.num_epochs,

@@ -97,7 +97,7 @@ def verify_quantize_kernels(
     interpret: bool = False,
     tol: float = 1e-6,
 ) -> float:
-    """Startup check (same role as ``launch/dfw.verify_kernelized``): the
+    """Startup check (same role as ``launch/dfw.verify_kernels``): the
     dispatched quantize/dequantize pair must match the jnp reference on a
     random probe. Returns the max abs error observed.
 

@@ -57,7 +57,6 @@ from . import low_rank
 from .frank_wolfe import (
     EpochAux,
     EpochCarry,
-    has_block_products,
     init_carry,
     init_probe,
     k_schedule,
@@ -242,12 +241,11 @@ def clear_programs() -> None:
 
 def code_of(task, reducer=None) -> tuple:
     """What the classes of ``task`` and ``reducer`` define that can be
-    called (and of what they wrap: a kernelized task's ``base``, a
-    topology's ``reducer``). A program key holds it beside the objects'
-    values, so a method patched on a class (as the benchmark's fault
-    harness patches ``update``) gives a program traced with the new code."""
-    objs = (task, getattr(task, "base", None),
-            reducer, getattr(reducer, "reducer", None))
+    called (and of what a topology wraps: its ``reducer``). A program key
+    holds it beside the objects' values, so a method patched on a class (as
+    the benchmark's fault harness patches ``update``) gives a program traced
+    with the new code."""
+    objs = (task, reducer, getattr(reducer, "reducer", None))
     return tuple(v for obj in objs for cls in type(obj).__mro__[:-1]
                  for v in vars(cls).values() if callable(v))
 
@@ -666,11 +664,6 @@ def run_epochs(
         compiled[sig] = program
         stats["compilations"] += 1
         stats["programs_reused"] += hit
-        if sspec.kind == "block" and tel.enabled:
-            # How the program's block LMO forms its products (built or
-            # reused alike), so a job's counters show the route it ran.
-            route = "kwide" if has_block_products(task) else "vmap"
-            tel.registry.counter(f"power.block_programs.{route}").inc()
         return program, not hit
 
     def _emit_executable_cost(seg: Segment, exe) -> None:

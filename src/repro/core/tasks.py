@@ -8,26 +8,59 @@ Interface (duck-typed; see ``frank_wolfe.DFWTask``):
     init_state(X, Y)      -> state pytree (local shard)
     matvec(state, v)      -> local  grad_j @ v          (d,)
     rmatvec(state, u)     -> local  grad_j^T @ u        (m,)
-    matmat(state, V)      -> local  grad_j @ V          (d,k)  (optional:
-    rmatmat(state, U)     -> local  grad_j^T @ U        (m,k)   one product)
+    matmat(state, V)      -> local  grad_j @ V          (d,k)  (block LMO)
+    rmatmat(state, U)     -> local  grad_j^T @ U        (m,k)
     update(state,u,v,g,mu)-> state after W <- (1-g)W - g*mu u v^T
     local_loss(state)     -> local loss contribution    ()
     inner_w_grad(state)   -> local <W, grad_j>          ()   (duality gap)
     local_grad(state)     -> dense local gradient (d,m)      (baselines only)
     linesearch(...)       -> optional closed-form step (MTLS only)
+
+The products run through the kernels (``kernels/power_matvec`` for X and
+R, ``kernels/mc_matvec`` for the observed entries); each task's
+``kernels`` field says how (``_KERNELS``).
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels.mc_matvec import ops as mc_ops
+from ..kernels.power_matvec import ops as pm_ops
 
-# Least squares pins its products to f32 (TPU's default precision rounds
-# f32 operands to bf16, and the residual R = XW - Y cancels what they carry).
+
+# XLA products pinned to f32: TPU's default precision rounds f32 operands
+# to bf16 (and least squares' residual R = XW - Y cancels what they carry).
 _HI = jax.lax.Precision.HIGHEST
+
+# A task's ``kernels`` field, as the kernel ops take it: "auto" leaves the
+# choice to the ops (Pallas on a TPU, the jnp reference elsewhere),
+# "pallas" forces the kernels (a TPU program lowered from another host),
+# "interpret" runs them in the Pallas interpreter (tests on the CPU), and
+# "ref" runs the jnp references: the f32 maths, the oracle that
+# ``launch/dfw.verify_kernels`` holds the other routes to.
+_KERNELS = {
+    "auto": dict(use_pallas=None, interpret=False),
+    "ref": dict(use_pallas=False, interpret=False),
+    "pallas": dict(use_pallas=True, interpret=False),
+    "interpret": dict(use_pallas=True, interpret=True),
+}
+
+
+def _kernel_kw(kernels: str) -> dict:
+    if kernels not in _KERNELS:
+        raise ValueError(f"kernels={kernels!r}; pick one of {sorted(_KERNELS)}")
+    return _KERNELS[kernels]
+
+
+def _columns(product, s, b: jax.Array) -> jax.Array:
+    """``product(s, .)`` on each column of the (., k) block ``b``: the block
+    products of a task whose kernels take one vector at a time."""
+    return jax.vmap(partial(product, s), in_axes=1, out_axes=1)(b)
 
 
 def _uvt(xu: jax.Array, v: jax.Array, precision=None) -> jax.Array:
@@ -75,19 +108,23 @@ class MTLSState(NamedTuple):
 class MultiTaskLeastSquares:
     d: int
     m: int
+    kernels: str = "auto"  # "auto" | "ref" | "pallas" | "interpret"
 
     def init_state(self, x: jax.Array, y: jax.Array) -> MTLSState:
         # W^0 = 0  =>  R = -Y
         return MTLSState(x=x, y=y, r=-y)
 
-    # grad = X^T R ; never materialized.
+    # grad = X^T R ; never materialized: two power_matvec passes.
     def matvec(self, s: MTLSState, v: jax.Array) -> jax.Array:
-        return jnp.dot(s.x.T, jnp.dot(s.r, v, precision=_HI), precision=_HI)
+        kw = _kernel_kw(self.kernels)
+        return pm_ops.rmatvec(s.x, pm_ops.matvec(s.r, v, **kw), **kw)
 
     def rmatvec(self, s: MTLSState, u: jax.Array) -> jax.Array:
-        return jnp.dot(s.r.T, jnp.dot(s.x, u, precision=_HI), precision=_HI)
+        kw = _kernel_kw(self.kernels)
+        return pm_ops.rmatvec(s.r, pm_ops.matvec(s.x, u, **kw), **kw)
 
-    # The same chains take a whole (m, k) / (d, k) probe block at once.
+    # The same chains take a whole (m, k) / (d, k) probe block at once:
+    # each pass reads X or R once for all k columns.
     def matmat(self, s: MTLSState, v: jax.Array) -> jax.Array:
         return self.matvec(s, v)
 
@@ -175,6 +212,7 @@ class LogisticState(NamedTuple):
 class MultinomialLogistic:
     d: int
     m: int
+    kernels: str = "auto"  # "auto" | "ref" | "pallas" | "interpret"
 
     def init_state(self, x: jax.Array, y: jax.Array) -> LogisticState:
         return LogisticState(x=x, y=y, z=jnp.zeros((x.shape[0], self.m), x.dtype))
@@ -182,14 +220,23 @@ class MultinomialLogistic:
     def _probs(self, s: LogisticState) -> jax.Array:
         return jax.nn.softmax(s.z, axis=-1)
 
-    # grad = X^T (P - H); H is one-hot(y). Never materialized.
+    # grad = X^T (P - H); H is one-hot(y). Never materialized. The X
+    # products are power_matvec passes; the P products stay in XLA, pinned
+    # to f32 (a TPU's default precision would round their operands to bf16).
     def matvec(self, s: LogisticState, v: jax.Array) -> jax.Array:
-        pv = self._probs(s) @ v - v[s.y]  # (n_j,)
-        return s.x.T @ pv
+        pv = jnp.dot(self._probs(s), v, precision=_HI) - v[s.y]  # (n_j,)
+        return pm_ops.rmatvec(s.x, pv, **_kernel_kw(self.kernels))
 
     def rmatvec(self, s: LogisticState, u: jax.Array) -> jax.Array:
-        t = s.x @ u  # (n_j,)
-        return self._probs(s).T @ t - jnp.zeros((self.m,), t.dtype).at[s.y].add(t)
+        t = pm_ops.matvec(s.x, u, **_kernel_kw(self.kernels))  # (n_j,)
+        return (jnp.dot(self._probs(s).T, t, precision=_HI)
+                - jnp.zeros((self.m,), t.dtype).at[s.y].add(t))
+
+    def matmat(self, s: LogisticState, v: jax.Array) -> jax.Array:
+        return _columns(self.matvec, s, v)
+
+    def rmatmat(self, s: LogisticState, u: jax.Array) -> jax.Array:
+        return _columns(self.rmatvec, s, u)
 
     def update(self, s: LogisticState, u, v, gamma, mu) -> LogisticState:
         z = (1.0 - gamma) * s.z - (gamma * mu) * _uvt(s.x @ u, v)
@@ -267,6 +314,7 @@ class MatrixCompletion:
 
     d: int
     m: int
+    kernels: str = "auto"  # "auto" | "ref" | "pallas" | "interpret"
 
     def init_state(self, idx: jax.Array, yw: jax.Array) -> MCState:
         # W^0 = 0  =>  resid = weight * (0 - M)
@@ -279,14 +327,18 @@ class MatrixCompletion:
 
     # grad @ v: scatter resid_e * v[col_e] into rows. Never materialized.
     def matvec(self, s: MCState, v: jax.Array) -> jax.Array:
-        return jax.ops.segment_sum(
-            s.resid * jnp.take(v, s.cols), s.rows, num_segments=self.d
-        )
+        return mc_ops.matvec(s.rows, s.cols, s.resid, v, self.d,
+                             **_kernel_kw(self.kernels))
 
     def rmatvec(self, s: MCState, u: jax.Array) -> jax.Array:
-        return jax.ops.segment_sum(
-            s.resid * jnp.take(u, s.rows), s.cols, num_segments=self.m
-        )
+        return mc_ops.rmatvec(s.rows, s.cols, s.resid, u, self.m,
+                              **_kernel_kw(self.kernels))
+
+    def matmat(self, s: MCState, v: jax.Array) -> jax.Array:
+        return _columns(self.matvec, s, v)
+
+    def rmatmat(self, s: MCState, u: jax.Array) -> jax.Array:
+        return _columns(self.rmatvec, s, u)
 
     def update(self, s: MCState, u, v, gamma, mu) -> MCState:
         # W' = (1-g)W - g mu u v^T on the observed entries:

@@ -60,11 +60,10 @@ def matvec(
 ) -> jax.Array:
     """A @ v: v (m,) -> (n,), or a block v (m, k) -> (n, k) in one pass over
     A. Zero-pads to block multiples (zeros are exact no-ops)."""
+    if not _use_pallas(use_pallas) and not interpret:
+        return ref.matvec(a, v)
     n, m = a.shape
     vb = v.reshape(m, -1)
-    if not _use_pallas(use_pallas) and not interpret:
-        out = ref.matvec(a, vb)
-        return out if v.ndim == 2 else out[:, 0]
     block_r, block_c = _tiles(n, m, vb.shape[1], block_r, block_c)
     ap = _pad_to(a, block_r, block_c)
     vp = _pad_to(vb, block_c, 1)
@@ -83,11 +82,10 @@ def rmatvec(
     interpret: bool = False,
 ) -> jax.Array:
     """A^T @ u: u (n,) -> (m,), or a block u (n, k) -> (m, k)."""
+    if not _use_pallas(use_pallas) and not interpret:
+        return ref.rmatvec(a, u)
     n, m = a.shape
     ub = u.reshape(n, -1)
-    if not _use_pallas(use_pallas) and not interpret:
-        out = ref.rmatvec(a, ub)
-        return out if u.ndim == 2 else out[:, 0]
     block_r, block_c = _tiles(n, m, ub.shape[1], block_r, block_c)
     ap = _pad_to(a, block_r, block_c)
     up = _pad_to(ub, block_r, 1)

@@ -4,16 +4,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# The f32 maths: a TPU's default precision would round f32 operands to bf16.
+_HI = jax.lax.Precision.HIGHEST
+
 
 def matvec(a: jax.Array, v: jax.Array) -> jax.Array:
-    """A @ v with f32 accumulation; v:(m,) or (m,1)."""
-    v = v.reshape(a.shape[1], -1)
-    return jnp.dot(a, v, preferred_element_type=jnp.float32)
+    """A @ v with f32 accumulation: v (m,) -> (n,), or a block (m, k) ->
+    (n, k). Unreshaped, so a ``vmap`` of the vector product is the block
+    product."""
+    return jnp.dot(a, v, precision=_HI, preferred_element_type=jnp.float32)
 
 
 def rmatvec(a: jax.Array, u: jax.Array) -> jax.Array:
-    u = u.reshape(a.shape[0], -1)
-    return jnp.dot(a.T, u, preferred_element_type=jnp.float32)
+    return jnp.dot(a.T, u, precision=_HI, preferred_element_type=jnp.float32)
 
 
 def power_iter_step(x: jax.Array, r: jax.Array, v: jax.Array):

@@ -14,11 +14,9 @@ the *machine* around them:
 - the paper's straggler/sampled-worker mode: a per-epoch Bernoulli schedule
   over workers precomputed as a (num_epochs, nw) weight array, indexed
   inside the engine's scan,
-- kernelized matvecs: the power-iteration hot path is routed through the
-  ``kernels/power_matvec`` Pallas ops (dense-state tasks) or
-  ``kernels/mc_matvec`` (observed-entry completion gradient) — one HBM pass
-  per call on TPU, jnp reference fallback elsewhere — with an up-front
-  correctness check against the task's pure-jnp operator chain,
+- an up-front check that the task's kernel products (its ``kernels``
+  route: Pallas on TPU, the jnp reference elsewhere) agree with its f32
+  maths (``verify_kernels``),
 - matrix-completion data layout: ``shard_observations`` partitions the
   observed entries into row-block worker shards padded to equal sizes with
   zero-weight no-op entries (static shapes under shard_map).
@@ -53,16 +51,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import comm as comm_lib, specs
 from ..checkpoint import dfw as ckpt
 from ..compat import shard_map_compat
-from ..core import engine, frank_wolfe, low_rank, tasks
+from ..core import engine, frank_wolfe, low_rank
 from ..core.frank_wolfe import EpochAux
 from ..obs import Telemetry
 from ..core.power_method import sphere_vector
-from ..kernels.mc_matvec import ops as mc_ops
-from ..kernels.power_matvec import ops as pm_ops
 from . import mesh as mesh_lib
 
 PyTree = Any
-_F32 = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -169,10 +164,7 @@ class DFWConfig:
     data_axis: str = "data"
     sample_prob: float = 1.0
     reweight: bool = True
-    kernelize: bool = True  # route matvecs through kernels/power_matvec
-    use_pallas: Optional[bool] = None  # None = auto (Pallas on TPU, jnp ref else)
-    interpret: bool = False  # Pallas interpreter mode (debugging)
-    verify_kernels: bool = True  # up-front kernel-vs-jnp agreement check
+    verify_kernels: bool = True  # up-front kernel-vs-f32 agreement check
     max_rank: Optional[int] = None  # factored-iterate capacity (default epochs)
     gap_tol: Optional[float] = None  # duality-gap early-stop threshold
     block_epochs: Optional[int] = None  # max epochs per scan segment
@@ -235,7 +227,7 @@ def shard_rowwise(mesh: Mesh, tree: PyTree, axis: str = "data") -> PyTree:
 
     def place(x):
         if not isinstance(x, jax.Array):
-            x = np.asarray(x)
+            x = np.asarray(x)  # REP002-ok: host data, past the isinstance(jax.Array) guard
         if x.shape[0] % nw:
             raise ValueError(
                 f"leading dim {x.shape[0]} not divisible by {nw} workers; "
@@ -348,114 +340,23 @@ def shard_observations(
 
 
 # ---------------------------------------------------------------------------
-# Kernelized tasks — power_matvec Pallas ops on the power-iteration hot path
+# Kernel check
 # ---------------------------------------------------------------------------
 
 
-class KernelizedTask:
-    """Delegating task wrapper that routes the streaming matvecs of the
-    power iteration through the Pallas kernels (paper Alg. 2 lines 5-10, the
-    per-epoch hot path): ``kernels/power_matvec`` for the dense-state tasks,
-    ``kernels/mc_matvec`` for the observed-entry (COO) completion gradient.
-
-    On TPU each call is a single-HBM-pass blocked Pallas kernel; elsewhere the
-    ops dispatch to the pure-jnp reference (``power_matvec/ref.py``), so the
-    wrapper is a no-op semantically everywhere. Everything except
-    matvec/rmatvec and matmat/rmatmat is delegated to the base task
-    untouched.
-    """
-
-    def __init__(
-        self,
-        base,
-        *,
-        use_pallas: Optional[bool] = None,
-        interpret: bool = False,
-    ):
-        self._base = base
-        self._kw = dict(use_pallas=use_pallas, interpret=interpret)
-
-    def __getattr__(self, name):
-        return getattr(self._base, name)
-
-    @property
-    def base(self):
-        return self._base
-
-    # Equal wrappers route alike, so the program cache keys on the value.
-    def _value(self):
-        return (self._base, self._kw["use_pallas"], self._kw["interpret"])
-
-    def __eq__(self, other):
-        return isinstance(other, KernelizedTask) and self._value() == other._value()
-
-    def __hash__(self):
-        return hash(self._value())
-
-    # -- implicit-gradient operator, kernel-routed per state type ----------
-    # (MTLSDenseState is not handled: the dense task lacks the epoch
-    # interface — local_loss/inner_w_grad — so the drivers here can't run it.)
-    # The logistic P-products stay in XLA; they are pinned to f32 precision
-    # (TPU's default would round their operands to bf16) so the whole
-    # operator agrees with the f32 maths ``verify_kernelized`` checks.
-    def matvec(self, s, v: jax.Array) -> jax.Array:
-        if isinstance(s, tasks.MTLSState):  # A = X^T R
-            return pm_ops.rmatvec(s.x, pm_ops.matvec(s.r, v, **self._kw), **self._kw)
-        if isinstance(s, tasks.LogisticState):  # A = X^T (P - H)
-            pv = jnp.dot(self._base._probs(s), v, precision=_F32) - v[s.y]
-            return pm_ops.rmatvec(s.x, pv, **self._kw)
-        if isinstance(s, tasks.MCState):  # A = P_Omega(W - M), COO values resid
-            return mc_ops.matvec(s.rows, s.cols, s.resid, v, self._base.d, **self._kw)
-        return self._base.matvec(s, v)
-
-    def rmatvec(self, s, u: jax.Array) -> jax.Array:
-        if isinstance(s, tasks.MTLSState):
-            return pm_ops.rmatvec(s.r, pm_ops.matvec(s.x, u, **self._kw), **self._kw)
-        if isinstance(s, tasks.LogisticState):
-            t = pm_ops.matvec(s.x, u, **self._kw)
-            p = self._base._probs(s)
-            return (jnp.dot(p.T, t, precision=_F32)
-                    - jnp.zeros((self._base.m,), t.dtype).at[s.y].add(t))
-        if isinstance(s, tasks.MCState):
-            return mc_ops.rmatvec(s.rows, s.cols, s.resid, u, self._base.m, **self._kw)
-        return self._base.rmatvec(s, u)
-
-    # k-wide products, present where the base task has them
-    # (``frank_wolfe.has_block_products``): the same kernel chains with a
-    # (., k) block, so each product is one pass over X and R.
-    def matmat(self, s, v: jax.Array) -> jax.Array:
-        if isinstance(s, tasks.MTLSState):
-            return self.matvec(s, v)
-        return self._base.matmat(s, v)
-
-    def rmatmat(self, s, u: jax.Array) -> jax.Array:
-        if isinstance(s, tasks.MTLSState):
-            return self.rmatvec(s, u)
-        return self._base.rmatmat(s, u)
-
-
-def kernelize(task, *, use_pallas: Optional[bool] = None, interpret: bool = False):
-    """Wrap ``task`` so its power-iteration matvecs run through the Pallas ops."""
-    if isinstance(task, KernelizedTask):
-        return task
-    return KernelizedTask(task, use_pallas=use_pallas, interpret=interpret)
-
-
-def verify_kernelized(
+def verify_kernels(
     task,
-    ktask: KernelizedTask,
     state: PyTree,
     key: jax.Array,
     *,
     tol: float = 1e-4,
     k: int = 1,
 ) -> float:
-    """Assert kernel-routed matvec/rmatvec match the base task's pure-jnp
-    operator chain (the same oracle ``kernels/power_matvec/ref.py`` encodes)
-    on random unit probes. Returns the max relative error observed.
+    """Assert the task's matvec/rmatvec match those of the same task with
+    ``kernels="ref"`` (its products through the kernels' jnp references) on
+    random unit probes. Returns the max relative error observed.
 
-    For a block solver of width ``k`` > 1 on a task with k-wide products
-    (``frank_wolfe.has_block_products``) it probes the route the block LMO
+    For a block solver of width ``k`` > 1 it probes the route the block LMO
     takes instead: ``matmat``/``rmatmat`` on one (m, k) and one (d, k)
     block of unit columns.
 
@@ -463,7 +364,7 @@ def verify_kernelized(
     matmul rounds operands to bf16) the check compares the kernels with the
     f32 maths, not with another precision mode."""
     kv, ku = jax.random.split(key)
-    if k > 1 and frank_wolfe.has_block_products(task):
+    if k > 1:
         v = jax.random.normal(kv, (task.m, k))
         u = jax.random.normal(ku, (task.d, k))
         v, u = v / jnp.linalg.norm(v, axis=0), u / jnp.linalg.norm(u, axis=0)
@@ -472,9 +373,10 @@ def verify_kernelized(
         v = sphere_vector(kv, task.m)
         u = sphere_vector(ku, task.d)
         fwd, bwd = "matvec", "rmatvec"
+    oracle = dataclasses.replace(task, kernels="ref")
     with jax.default_matmul_precision("highest"):
-        want_v = getattr(task, fwd)(state, v)
-        want_u = getattr(task, bwd)(state, u)
+        want_v = getattr(oracle, fwd)(state, v)
+        want_u = getattr(oracle, bwd)(state, u)
 
     def rel_err(a, b):
         a, b = jnp.asarray(a), jnp.asarray(b)
@@ -485,12 +387,12 @@ def verify_kernelized(
         ))
 
     err = max(
-        rel_err(getattr(ktask, fwd)(state, v), want_v),
-        rel_err(getattr(ktask, bwd)(state, u), want_u),
+        rel_err(getattr(task, fwd)(state, v), want_v),
+        rel_err(getattr(task, bwd)(state, u), want_u),
     )
     if err > tol:
         raise AssertionError(
-            f"kernelized matvec diverges from jnp reference: rel err {err:.3e} "
+            f"kernel products diverge from the f32 reference: rel err {err:.3e} "
             f"> tol {tol:.1e} (task={type(task).__name__})"
         )
     return err
@@ -576,7 +478,6 @@ def make_sharded_epoch(
         comm_obj = comm_lib.make_topology(
             cfg.topology, num_workers=mesh.shape[axis], comm=cfg.comm,
             rounds=cfg.gossip_rounds,
-            use_pallas=cfg.use_pallas, interpret=cfg.interpret,
         )
         reducer = comm_obj.reducer
     per_node = bool(getattr(comm_obj, "per_node", False))  # REP002-ok: host attribute
@@ -761,33 +662,26 @@ def fit(
         topo = comm_lib.make_topology(
             cfg.topology, num_workers=nw, comm=cfg.comm,
             rounds=cfg.gossip_rounds,
-            use_pallas=cfg.use_pallas, interpret=cfg.interpret,
         )
         reducer = topo.reducer
         comm_obj = reducer if tspec.kind == "flat" else topo
         per_node = bool(getattr(comm_obj, "per_node", False))  # REP002-ok: host attribute
 
-        ktask = (
-            kernelize(task, use_pallas=cfg.use_pallas, interpret=cfg.interpret)
-            if cfg.kernelize
-            else task
-        )
         with tel.span("engine.verify_kernels", "engine"):
-            if cfg.kernelize and cfg.verify_kernels:
+            if cfg.verify_kernels:
                 # Probe on a small host-local slice before committing to the run.
                 probe_rows = min(x.shape[0], max(nw, 64))
                 probe = task.init_state(x[:probe_rows], y[:probe_rows])
-                verify_kernelized(task, ktask, probe, jax.random.fold_in(key, 0x5EED),
-                                  k=k_block)
+                verify_kernels(task, probe, jax.random.fold_in(key, 0x5EED),
+                               k=k_block)
             if isinstance(reducer, comm_lib.Int8Reducer) and cfg.verify_kernels:
                 comm_lib.verify_quantize_kernels(
-                    jax.random.fold_in(key, 0x17F8),
-                    num_workers=nw, use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+                    jax.random.fold_in(key, 0x17F8), num_workers=nw,
                 )
 
         with tel.span("engine.place", "engine"):
             xs, ys = shard_rowwise(mesh, (x, y), cfg.data_axis)
-            state = init_rowwise_state(ktask, xs, ys, cfg.data_axis, tel)
+            state = init_rowwise_state(task, xs, ys, cfg.data_axis, tel)
             it = low_rank.init(max_rank, task.d, task.m)
 
             # Per-worker reducer state: every worker starts from the reducer's own
@@ -881,7 +775,7 @@ def fit(
                 # Nothing left to run: the checkpoint already holds the final
                 # carry (epoch budget spent, or its gap certificate still
                 # stands under this config's gap_tol).
-                loss, reused = engine.final_loss(ktask, state, tel)
+                loss, reused = engine.final_loss(task, state, tel)
                 return DFWFitResult(
                     iterate=it, state=state, history=snap.history,
                     masks=masks[: snap.t] if sampling else None,
@@ -928,7 +822,7 @@ def fit(
             per_node_iterate=per_node,
         )
         eres = engine.run_epochs(
-            ktask,
+            task,
             state,
             mu=cfg.mu,
             num_epochs=cfg.num_epochs,
@@ -964,7 +858,7 @@ def fit(
         # The plain sum over the row-sharded state is already the global loss, and
         # straggler weights never apply here: this is the true full-data F.
         with tel.span("engine.final_loss", "engine"):
-            loss, reused = engine.final_loss(ktask, eres.carry.state, tel)
+            loss, reused = engine.final_loss(task, eres.carry.state, tel)
         eres.stats["dispatches"] += 1
         eres.stats["host_syncs"] += 1
         eres.stats["compilations"] += 1
@@ -996,7 +890,7 @@ def fit_serial(
     callback: Optional[Callable[[int, EpochAux], None]] = None,
 ) -> DFWFitResult:
     """Single-device reference run with the *same* config (and the same
-    kernelized matvec path) as ``fit`` — the baseline every sharded run is
+    kernel products) as ``fit`` — the baseline every sharded run is
     compared against in tests and benchmarks.
 
     ``cfg.comm`` is honored with a one-worker reducer: the serial run
@@ -1018,23 +912,17 @@ def fit_serial(
             "from; fit_serial runs exactly one. Use fit(..., num_workers=N) "
             "for the straggler mode, or set sample_prob=1.0"
         )
-    ktask = (
-        kernelize(task, use_pallas=cfg.use_pallas, interpret=cfg.interpret)
-        if cfg.kernelize
-        else task
-    )
     sspec, _, tspec = specs.validate(
         solver=cfg.solver, comm=cfg.comm, topology=cfg.topology
     )
     topo = comm_lib.make_topology(
         cfg.topology, num_workers=1, comm=cfg.comm,
         rounds=cfg.gossip_rounds,
-        use_pallas=cfg.use_pallas, interpret=cfg.interpret,
     )
     reducer = topo.reducer
     comm_obj = reducer if tspec.kind == "flat" else topo
     k_block = sspec.k if sspec.kind == "block" else 1
-    state = ktask.init_state(jnp.asarray(x), jnp.asarray(y))
+    state = task.init_state(jnp.asarray(x), jnp.asarray(y))
     iterate, comm_state, start_t, initial_history = None, None, 0, None
     probe = None
     if cfg.resume_from is not None:
@@ -1066,7 +954,7 @@ def fit_serial(
         # per-worker residuals don't transfer to the one-worker run, and a
         # warm comm change starts its new encoding from scratch.
         if _resume_complete(snap, cfg):
-            loss, reused = engine.final_loss(ktask, state, cfg.telemetry)
+            loss, reused = engine.final_loss(task, state, cfg.telemetry)
             return DFWFitResult(
                 iterate=iterate, state=state, history=snap.history,
                 masks=None, final_loss=loss, epochs_run=snap.t,
@@ -1080,7 +968,7 @@ def fit_serial(
         # start_t (all of them, for a fresh run).
         checkpointer.store.discard_after(start_t)
     res = frank_wolfe.fit(
-        ktask,
+        task,
         state,
         mu=cfg.mu,
         num_epochs=cfg.num_epochs,

@@ -434,7 +434,7 @@ class ServingEngine:
     # ------------------------------------------------------------- verify
     def _verify_once(self, model: Model) -> None:
         """First-load startup check (same role as ``launch/dfw.
-        verify_kernelized``): the configured kernel path must agree with
+        verify_kernels``): the configured kernel path must agree with
         the dense materialized oracle before any traffic is scored."""
         if self._verified:
             return

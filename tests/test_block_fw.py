@@ -420,16 +420,19 @@ def test_block_power_kwide_route_matches_vmap_route():
     np.testing.assert_allclose(got[2], want[2], atol=1e-5)
 
 
-def test_kernelized_matmat_matches_base_task_at_highest():
-    task, x, y = _lsq_cut(jax.random.PRNGKey(21), n=300, d=40, m=28)
+def test_interpret_matmat_matches_ref_at_highest():
+    """The k-wide Pallas kernels (in the interpreter) against the task's
+    own f32 maths (``kernels="ref"``)."""
+    ref, x, y = _lsq_cut(jax.random.PRNGKey(21), n=300, d=40, m=28)
+    ref = dataclasses.replace(ref, kernels="ref")
+    task = dataclasses.replace(ref, kernels="interpret")
     state = task.init_state(x, y)
-    ktask = dfw.kernelize(task, interpret=True)
     v = jax.random.normal(jax.random.PRNGKey(4), (task.m, 5))
     u = jax.random.normal(jax.random.PRNGKey(5), (task.d, 5))
     with jax.default_matmul_precision("highest"):
-        want_v, want_u = task.matmat(state, v), task.rmatmat(state, u)
-    for got, want in ((ktask.matmat(state, v), want_v),
-                      (ktask.rmatmat(state, u), want_u)):
+        want_v, want_u = ref.matmat(state, v), ref.rmatmat(state, u)
+    for got, want in ((task.matmat(state, v), want_v),
+                      (task.rmatmat(state, u), want_u)):
         assert got.shape == want.shape
         np.testing.assert_allclose(
             got, want, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(want))))
@@ -457,11 +460,6 @@ class _SpyCompletion(tasks.MatrixCompletion):
 
 def test_completion_block_lmo_takes_the_vmap_fallback():
     task = _SpyCompletion(d=30, m=24)
-    assert not frank_wolfe.has_block_products(task)
-    assert not frank_wolfe.has_block_products(dfw.kernelize(task))
-    assert not frank_wolfe.has_block_products(tasks.MultinomialLogistic(d=8, m=4))
-    assert frank_wolfe.has_block_products(dfw.kernelize(
-        tasks.MultiTaskLeastSquares(d=8, m=4)))
     _PROBE_NDIMS.clear()
     res = frank_wolfe.fit(
         task, task.init_state(*_completion_obs(jax.random.PRNGKey(6))),
@@ -471,32 +469,38 @@ def test_completion_block_lmo_takes_the_vmap_fallback():
     assert _PROBE_NDIMS and set(_PROBE_NDIMS) == {1}
 
 
-@pytest.mark.parametrize("kind", ["lsq", "completion"])
-def test_block_program_counters_count_the_route(kind):
-    from repro.obs import Telemetry
+def _logistic_state(key, n=64, d=24, m=18):
+    task = tasks.MultinomialLogistic(d=d, m=m)
+    x = jax.random.normal(key, (n, d))
+    s = task.init_state(x, jax.random.randint(jax.random.fold_in(key, 1), (n,), 0, m))
+    # a nonzero iterate, so P is not uniform
+    s = task.update(s, jax.random.normal(jax.random.fold_in(key, 2), (d,)),
+                    jax.random.normal(jax.random.fold_in(key, 3), (m,)), 0.5, 1.0)
+    return task, s
 
+
+@pytest.mark.parametrize("kind", ["lsq", "logistic", "completion"])
+def test_block_products_stack_the_vector_products(kind):
+    """Every task's ``matmat``/``rmatmat``, the products the block LMO
+    takes, are its ``matvec``/``rmatvec`` on each column, stacked."""
+    key = jax.random.PRNGKey(22)
     if kind == "lsq":
-        x, y = _mtls(jax.random.PRNGKey(11), n=64)
         task = tasks.MultiTaskLeastSquares(d=24, m=18)
-        state, route = task.init_state(x, y), "kwide"
+        state = task.init_state(*_mtls(key, n=64))
+    elif kind == "logistic":
+        task, state = _logistic_state(key)
     else:
         task = tasks.MatrixCompletion(d=30, m=24)
-        state = task.init_state(*_completion_obs(jax.random.PRNGKey(6)))
-        route = "vmap"
-    tel = Telemetry()
-    for _ in range(2):  # the second fit reuses the cached program
-        frank_wolfe.fit(task, state, mu=1.0, num_epochs=4,
-                        key=jax.random.PRNGKey(1), solver="block:3",
-                        telemetry=tel)
-    counters = tel.registry.snapshot()["counters"]
-    assert counters[f"power.block_programs.{route}"] == 2
-    other = "vmap" if route == "kwide" else "kwide"
-    assert f"power.block_programs.{other}" not in counters
-    # a rank-1 run builds no block program
-    frank_wolfe.fit(task, state, mu=1.0, num_epochs=2,
-                    key=jax.random.PRNGKey(1), telemetry=tel)
-    assert tel.registry.snapshot()["counters"][
-        f"power.block_programs.{route}"] == 2
+        state = task.init_state(*_completion_obs(key))
+    v = jax.random.normal(jax.random.fold_in(key, 4), (task.m, 3))
+    u = jax.random.normal(jax.random.fold_in(key, 5), (task.d, 3))
+    for block, vector, probe in ((task.matmat, task.matvec, v),
+                                 (task.rmatmat, task.rmatvec, u)):
+        want = jnp.stack([vector(state, probe[:, j]) for j in range(3)], axis=1)
+        got = block(state, probe)
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-6 * float(jnp.max(jnp.abs(want))))
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ Multi-device coverage runs in subprocesses with 8 fake CPU devices (the
 device count locks at the first jax init in the main pytest process); the
 kernel-routing and worker-schedule units run in-process on one device.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -192,7 +193,7 @@ def test_shard_rowwise_sends_host_rows_to_their_devices():
             for s in shards:
                 np.testing.assert_array_equal(np.asarray(s.data), host[s.index])
                 assert s.data.shape[0] == 8
-        task = dfw.kernelize(tasks.MultinomialLogistic(d=3, m=5))
+        task = tasks.MultinomialLogistic(d=3, m=5)
         st = dfw.init_rowwise_state(task, xs, ys)
         assert st.x is xs and st.y is ys  # the data is not copied
         assert st.z.sharding == NamedSharding(mesh, P("data"))
@@ -243,79 +244,86 @@ def test_compile_cache_is_fixed_or_left_to_the_environment(monkeypatch):
 
 @pytest.mark.parametrize("taskcls", [tasks.MultiTaskLeastSquares,
                                      tasks.MultinomialLogistic])
-def test_kernelized_matches_base_task(taskcls):
+def test_task_kernels_match_the_ref_route(taskcls):
+    """The Pallas kernels (in the interpreter) against the task's f32
+    maths, and dfw.verify_kernels on them."""
     n, d, m = 192, 24, 16
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (n, d))
-    task = taskcls(d=d, m=m)
+    task = taskcls(d=d, m=m, kernels="interpret")
+    ref = dataclasses.replace(task, kernels="ref")
     if taskcls is tasks.MultinomialLogistic:
         y = jax.random.randint(jax.random.fold_in(key, 1), (n,), 0, m)
     else:
         y = jax.random.normal(jax.random.fold_in(key, 1), (n, m))
     state = task.init_state(x, y)
-    ktask = dfw.kernelize(task)
     v = jax.random.normal(jax.random.fold_in(key, 2), (m,))
     u = jax.random.normal(jax.random.fold_in(key, 3), (d,))
-    np.testing.assert_allclose(np.asarray(ktask.matvec(state, v)),
-                               np.asarray(task.matvec(state, v)),
+    np.testing.assert_allclose(np.asarray(task.matvec(state, v)),
+                               np.asarray(ref.matvec(state, v)),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(ktask.rmatvec(state, u)),
-                               np.asarray(task.rmatvec(state, u)),
+    np.testing.assert_allclose(np.asarray(task.rmatvec(state, u)),
+                               np.asarray(ref.rmatvec(state, u)),
                                rtol=1e-5, atol=1e-5)
     # the up-front driver check agrees too
-    err = dfw.verify_kernelized(task, ktask, state, jax.random.fold_in(key, 4))
-    assert err < 1e-4
-    # delegation: everything but matvec/rmatvec reaches the base task
-    assert ktask.d == d and ktask.m == m
-    assert float(ktask.local_loss(state)) == float(task.local_loss(state))
+    assert dfw.verify_kernels(task, state, jax.random.fold_in(key, 4)) < 1e-4
 
 
-def test_kernelized_mtls_matches_power_matvec_ref():
-    """The kernel route == an explicit chain through power_matvec/ref.py."""
+def test_mtls_products_chain_power_matvec_ref():
+    """The least-squares products == an explicit chain through
+    power_matvec/ref.py."""
     n, d, m = 128, 20, 12
     key = jax.random.PRNGKey(5)
     x = jax.random.normal(key, (n, d))
     y = jax.random.normal(jax.random.fold_in(key, 1), (n, m))
     task = tasks.MultiTaskLeastSquares(d=d, m=m)
     s = task.init_state(x, y)
-    ktask = dfw.kernelize(task)
     v = jax.random.normal(jax.random.fold_in(key, 2), (m,))
-    want = pm_ref.rmatvec(s.x, pm_ref.matvec(s.r, v))[:, 0]
-    np.testing.assert_allclose(np.asarray(ktask.matvec(s, v)),
+    want = pm_ref.rmatvec(s.x, pm_ref.matvec(s.r, v))
+    np.testing.assert_allclose(np.asarray(task.matvec(s, v)),
                                np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
-def test_verify_kernelized_catches_divergence():
+class _DoubledMatvec(tasks.MultiTaskLeastSquares):
+    """Least squares whose kernel route (any but "ref") doubles A v."""
+
+    def matvec(self, st, v):
+        out = super().matvec(st, v)
+        return out if self.kernels == "ref" else 2.0 * out
+
+
+class _DoubledRmatmat(tasks.MultiTaskLeastSquares):
+    """Least squares whose kernel route doubles the block product A^T U
+    alone."""
+
+    def rmatmat(self, st, u):
+        out = super().rmatmat(st, u)
+        return out if self.kernels == "ref" else 2.0 * out
+
+
+def _small_mtls_state(key):
     task = tasks.MultiTaskLeastSquares(d=8, m=6)
+    return task.init_state(jax.random.normal(key, (32, 8)),
+                           jax.random.normal(jax.random.fold_in(key, 1), (32, 6)))
+
+
+def test_verify_kernels_catches_divergence():
     key = jax.random.PRNGKey(6)
-    s = task.init_state(jax.random.normal(key, (32, 8)),
-                        jax.random.normal(jax.random.fold_in(key, 1), (32, 6)))
-
-    class Broken(dfw.KernelizedTask):
-        def matvec(self, st, v):
-            return 2.0 * super().matvec(st, v)
-
-    with pytest.raises(AssertionError, match="diverges"):
-        dfw.verify_kernelized(task, Broken(task), s, key)
+    s = _small_mtls_state(key)
+    with pytest.raises(AssertionError, match="diverge"):
+        dfw.verify_kernels(_DoubledMatvec(d=8, m=6), s, key)
 
 
-def test_verify_kernelized_probes_the_block_route():
-    """For a block solver on a task with k-wide products the check runs
-    matmat/rmatmat: a fault there alone passes the vector probes and trips
-    the block ones."""
-    task = tasks.MultiTaskLeastSquares(d=8, m=6)
+def test_verify_kernels_probes_the_block_route():
+    """For a block solver (k > 1) the check runs matmat/rmatmat: a fault
+    there alone passes the vector probes and trips the block ones."""
     key = jax.random.PRNGKey(8)
-    s = task.init_state(jax.random.normal(key, (32, 8)),
-                        jax.random.normal(jax.random.fold_in(key, 1), (32, 6)))
-
-    class BrokenBlock(dfw.KernelizedTask):
-        def rmatmat(self, st, u):
-            return 2.0 * super().rmatmat(st, u)
-
-    assert dfw.verify_kernelized(task, dfw.kernelize(task), s, key, k=4) < 1e-4
-    assert dfw.verify_kernelized(task, BrokenBlock(task), s, key) < 1e-4
-    with pytest.raises(AssertionError, match="diverges"):
-        dfw.verify_kernelized(task, BrokenBlock(task), s, key, k=4)
+    s = _small_mtls_state(key)
+    task = tasks.MultiTaskLeastSquares(d=8, m=6)
+    assert dfw.verify_kernels(task, s, key, k=4) < 1e-4
+    assert dfw.verify_kernels(_DoubledRmatmat(d=8, m=6), s, key) < 1e-4
+    with pytest.raises(AssertionError, match="diverge"):
+        dfw.verify_kernels(_DoubledRmatmat(d=8, m=6), s, key, k=4)
 
 
 def _bf16(a):
@@ -324,7 +332,7 @@ def _bf16(a):
 
 @pytest.mark.parametrize("taskcls", [tasks.MultiTaskLeastSquares,
                                      tasks.MultinomialLogistic])
-def test_verify_kernelized_catches_bf16_operands(taskcls):
+def test_verify_kernels_catches_bf16_operands(taskcls):
     """A kernel that rounds its operands to bf16 (what TPU's default matmul
     precision does to f32) must trip the check: its oracle runs at
     Precision.HIGHEST, so precision loss is not hidden on either side."""
@@ -339,16 +347,18 @@ def test_verify_kernelized_catches_bf16_operands(taskcls):
                     jax.random.normal(jax.random.fold_in(key, 3), (m,)),
                     0.5, 1.0)  # a nonzero iterate: Z/R carry real values
 
-    class Bf16Operands(dfw.KernelizedTask):
+    class Bf16Operands(taskcls):
         def matvec(self, st, v):
-            rounded = jax.tree.map(
-                lambda a: _bf16(a) if jnp.issubdtype(a.dtype, jnp.floating)
-                else a, st)
-            return super().matvec(rounded, _bf16(v))
+            if self.kernels != "ref":
+                st = jax.tree.map(
+                    lambda a: _bf16(a) if jnp.issubdtype(a.dtype, jnp.floating)
+                    else a, st)
+                v = _bf16(v)
+            return super().matvec(st, v)
 
-    assert dfw.verify_kernelized(task, dfw.kernelize(task), s, key) < 1e-4
-    with pytest.raises(AssertionError, match="diverges"):
-        dfw.verify_kernelized(task, Bf16Operands(task), s, key)
+    assert dfw.verify_kernels(task, s, key) < 1e-4
+    with pytest.raises(AssertionError, match="diverge"):
+        dfw.verify_kernels(Bf16Operands(d=d, m=m), s, key)
 
 
 def test_fit_serial_rejects_sample_prob():

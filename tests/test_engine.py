@@ -481,19 +481,19 @@ _REUSE_CHANGES = {
     "comm": {"comm": "int8"},
     "topology": {"topology": "ring"},
     "workers": {"workers": 4},
-    "kernelize": {"kernelize": False},
-    "use_pallas": {"use_pallas": False},
+    "kernels_ref": {"kernels": "ref"},
+    "kernels_interpret": {"kernels": "interpret"},
     "task_d_m": {"d": 20, "m": 16},
 }
 
 
-def _reuse_job(tel, key, d=24, m=18, workers=1, **cfg_kw):
+def _reuse_job(tel, key, d=24, m=18, workers=1, kernels="auto", **cfg_kw):
     """One ``launch/dfw.fit`` job of a small MTLS problem; returns its result
     and the change of ``tel``'s counters and the names of its spans."""
     from repro.launch import dfw
 
     x, y = _mtls(jax.random.PRNGKey(12), n=400, d=d, m=m)
-    task = tasks.MultiTaskLeastSquares(d=d, m=m)
+    task = tasks.MultiTaskLeastSquares(d=d, m=m, kernels=kernels)
     cfg = dfw.DFWConfig(**{"mu": 1.0, "num_epochs": 6, "schedule": "const:2",
                            "step_size": "linesearch", "telemetry": tel,
                            **cfg_kw})

@@ -4,6 +4,8 @@ The task state is O(|Omega_j|) COO shards; every check here compares the
 segment-gather/scatter chains against an explicitly materialized d x m
 simulation of the same FW trajectory (small instances only).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -188,20 +190,22 @@ def test_completion_recovers_low_rank_matrix():
     assert ho_rmse(res.iterate) < 0.5 * base
 
 
-def test_kernelized_mc_matches_base_task():
+def test_mc_kernels_match_the_ref_route():
+    """The COO Pallas kernels (in the interpreter) against the task's f32
+    maths, and dfw.verify_kernels on them."""
     rows, cols, vals, _ = _mc_problem(KEY)
-    task = tasks.MatrixCompletion(d=30, m=24)
+    task = tasks.MatrixCompletion(d=30, m=24, kernels="interpret")
+    ref = dataclasses.replace(task, kernels="ref")
     s = task.init_state(*tasks.pack_observations(rows, cols, vals))
-    ktask = dfw.kernelize(task)
     v = jax.random.normal(jax.random.fold_in(KEY, 10), (24,))
     u = jax.random.normal(jax.random.fold_in(KEY, 11), (30,))
-    np.testing.assert_allclose(np.asarray(ktask.matvec(s, v)),
-                               np.asarray(task.matvec(s, v)),
+    np.testing.assert_allclose(np.asarray(task.matvec(s, v)),
+                               np.asarray(ref.matvec(s, v)),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(ktask.rmatvec(s, u)),
-                               np.asarray(task.rmatvec(s, u)),
+    np.testing.assert_allclose(np.asarray(task.rmatvec(s, u)),
+                               np.asarray(ref.rmatvec(s, u)),
                                rtol=1e-5, atol=1e-5)
-    err = dfw.verify_kernelized(task, ktask, s, jax.random.fold_in(KEY, 12))
+    err = dfw.verify_kernels(task, s, jax.random.fold_in(KEY, 12))
     assert err < 1e-4
 
 

@@ -22,7 +22,6 @@ from repro.kernels.factor_matvec import ops as fm_ops
 from repro.kernels.mc_matvec import ops as mc_ops
 from repro.kernels.power_matvec import ops as pm_ops
 from repro.kernels.quantize import ops as q_ops
-from repro.launch.dfw import kernelize
 
 HEAD_ROWS = 327_680  # one chip's share of 1.28M ImageNet rows over 4 chips
 F32, I32 = jnp.float32, jnp.int32
@@ -122,10 +121,10 @@ def test_block32_mtls_epoch_compiles_at_table1_width(one_chip):
     exchange, line search) at Table-1 width: the whole epoch program lowers
     for v5e with its matvecs and the quantize pair as kernels."""
     n, d, m, k, epochs = 65_536, 1024, 1024, 32, 10
-    task = tasks.MultiTaskLeastSquares(d=d, m=m)
+    task = tasks.MultiTaskLeastSquares(d=d, m=m, kernels="pallas")
     sspec = frank_wolfe.parse_solver(f"block:{k}")
     epoch = frank_wolfe.make_epoch_step(
-        kernelize(task, use_pallas=True), 1.0, 2, step_size="linesearch",
+        task, 1.0, 2, step_size="linesearch",
         solver=sspec, reducer=Int8Reducer(num_workers=1, use_pallas=True),
     )
     carry = jax.eval_shape(lambda: frank_wolfe.init_carry(
